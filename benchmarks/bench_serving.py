@@ -632,6 +632,9 @@ def main(argv=None):
     if args.backend == "mesh" and "XLA_FLAGS" not in os.environ:
         # must happen before any jax import in this process
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.replicas:
         return run_replication_smoke(args)
     if args.absorb:

@@ -876,6 +876,9 @@ def main() -> None:
         "measured per-unit time)",
     )
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     print("name,us_per_call,derived")
     for name, fn in _BENCHES.items():
         if args.only and name not in args.only:

@@ -40,7 +40,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.kernels import ops
 from repro.sharding.logical import folded_axis_index, mesh_axis_size
 
@@ -209,7 +208,7 @@ def make_apsp_segment(
         split_panels=split_panels,
     )
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(data_axis, model_axis), P(), P()),

@@ -15,8 +15,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro import compat
-
 
 @jax.jit
 def double_center(a_sq: jax.Array) -> jax.Array:
@@ -48,7 +46,7 @@ def double_center_local(a_sq_loc, *, data_axis: str, model_axis: str, n: int):
 def double_center_sharded(a_sq: jax.Array, mesh: Mesh,
                           data_axis: str = "data", model_axis: str = "model"):
     n = a_sq.shape[0]
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         lambda t: double_center_local(
             t, data_axis=data_axis, model_axis=model_axis, n=n
         ),

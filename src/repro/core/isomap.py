@@ -29,7 +29,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro import compat
 from repro.core import spectral
 from repro.kernels import ops
 from repro.core.pipeline import (
@@ -232,7 +231,7 @@ def make_landmark_init_sharded(
         dl_cols = jax.lax.psum(sl, data_axis)
         return jax.lax.all_gather(dl_cols, model_axis, axis=1, tiled=True)
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         shard_fn,
         mesh=mesh,
         in_specs=P(data_axis, model_axis),
@@ -274,7 +273,7 @@ def make_landmark_sweep_sharded(
 
         return jax.lax.fori_loop(0, sweeps, relax, dl)
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         shard_fn,
         mesh=mesh,
         in_specs=(P(data_axis, model_axis), P(), P()),

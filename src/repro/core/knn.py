@@ -37,18 +37,18 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro import compat
 from repro.kernels import ops
+from repro.kernels.ref import topk_by_index
 
 _BIG = jnp.float32(jnp.inf)
 
 
 def _fold_topk(best_d, best_i, new_d, new_i, k: int):
     """Merge running (b, k) top-k with a new (b, c) candidate block."""
-    d = jnp.concatenate([best_d, new_d], axis=1)
-    i = jnp.concatenate([best_i, new_i], axis=1)
-    neg, pos = jax.lax.top_k(-d, k)
-    return -neg, jnp.take_along_axis(i, pos, axis=1)
+    return topk_by_index(
+        jnp.concatenate([best_d, new_d], axis=1),
+        jnp.concatenate([best_i, new_i], axis=1), k,
+    )
 
 
 @functools.partial(jax.jit, static_argnames=("k", "block", "mode"))
@@ -238,13 +238,11 @@ def knn_ring(
             # merge the split groups' candidate lists
             all_d = jax.lax.all_gather(best_d, split_axis, axis=1, tiled=True)
             all_i = jax.lax.all_gather(best_i, split_axis, axis=1, tiled=True)
-            neg, pos = jax.lax.top_k(-all_d, k)
-            best_d = -neg
-            best_i = jnp.take_along_axis(all_i, pos, axis=1)
+            best_d, best_i = topk_by_index(all_d, all_i, k)
         return best_d, best_i
 
     in_spec = P(row_axis, feat_axis) if feat_axis else P(row_axis, None)
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         shard_fn,
         mesh=mesh,
         in_specs=in_spec,

@@ -48,7 +48,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.core import spectral
 from repro.core.postprocess import clamp_disconnected, embedding_from_eig
 from repro.kernels import autotune, ops
@@ -260,7 +259,7 @@ def make_sparse_segment_sharded(
             mode=mode, max_rounds=max_rounds,
         )
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         shard_fn,
         mesh=mesh,
         in_specs=(P(), P(), P(), P(folded, None), P(), P(), P()),
@@ -409,7 +408,7 @@ class SparseGeodesicStage:
     """Exact landmark geodesics over the CSR graph, as a ResumableStage.
 
     Units are landmark batches (batch size from the frontier autotuner's
-    VMEM residency bound), state is the growing (m, n) panel — so
+    batch cap), state is the growing (m, n) panel — so
     checkpoint/resume and ``--checkpoint-secs`` calibration work through
     the engine unchanged, and a kill mid-panel re-enters at the recorded
     batch.  ``segment_requires`` keeps the CSR graph + landmark set in

@@ -21,8 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro import compat
-
 
 class EigResult(NamedTuple):
     eigenvectors: jax.Array   # (n, d)
@@ -134,7 +132,7 @@ def make_power_iteration_sharded(
         order = jnp.argsort(-jnp.abs(lam))
         return EigResult(q[:, order], lam[order], it, delta)
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         shard_fn,
         mesh=mesh,
         in_specs=P(data_axis, model_axis),

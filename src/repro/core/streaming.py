@@ -40,7 +40,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.core.artifacts import VersionedArtifacts
 from repro.kernels import ops
 
@@ -131,7 +130,7 @@ def _make_row_mean_sq_sharded(mesh, n, data_axis, model_axis):
             data_axis=data_axis, model_axis=model_axis, nc=nc,
         )[:, 0]                                          # (n,) replicated
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=P(data_axis, model_axis), out_specs=P(),
         check_vma=False,
@@ -183,7 +182,7 @@ def _make_new_point_geo_sharded(mesh, n, k, data_axis, model_axis, mode):
             x_new, xb_loc, a_loc, k, nr, data_axis, model_axis, mode
         )
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(P(), P(data_axis), P(data_axis, model_axis)),
         out_specs=P(),
@@ -238,7 +237,7 @@ def _make_map_new_points_sharded(
         pinv = _eigenbasis_pinv(y_base)
         return -0.5 * (jnp.square(geo) - mean_sq[None, :]) @ pinv
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         shard_fn,
         mesh=mesh,
         in_specs=(

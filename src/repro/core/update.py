@@ -71,7 +71,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.core import metrics
 from repro.kernels import ops
 
@@ -381,7 +380,7 @@ def make_expand_sharded(
         a_loc = update(a_loc, b_rows.T, b_loc)
         return a_loc, b_full, d
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         shard_fn,
         mesh=mesh,
         in_specs=(P(data_axis, model_axis), P(), P()),

@@ -56,18 +56,73 @@ import os
 from typing import Iterator, NamedTuple
 
 # ----------------------------------------------------------- machine model --
-# TPU v5e constants (per chip).  Single source of truth: repro.launch
-# .analytics and repro.launch.dryrun import these for their stage-level
-# rooflines, so the kernel tuner and the pipeline cost model can never
-# disagree about the hardware.
-PEAK_FLOPS = 197e12     # bf16 FLOP/s (MXU) - reference only; min-plus is VPU
-VPU_OPS = 3.9e12        # f32 elementwise ops/s (8x128 lanes x 4 ALUs)
-HBM_BW = 819e9          # bytes/s
-ICI_BW = 50e9           # bytes/s per link
-VMEM_BYTES = 16 * 2**20  # per-core vector memory
-# the pipelined working set (double-buffered streamed inputs) must fit
-# with headroom for the compiler's own temporaries
-VMEM_BUDGET = VMEM_BYTES // 2
+
+
+class Chip(NamedTuple):
+    """Per-chip machine constants of one TPU generation."""
+
+    peak_flops: float   # bf16 FLOP/s (MXU) - reference only; min-plus is VPU
+    vpu_ops: float      # f32 elementwise ops/s
+    hbm_bw: float       # bytes/s
+    ici_bw: float       # bytes/s per link
+    vmem_bytes: int     # scoped VMEM a kernel gets without vmem_limit_bytes
+    source: str
+
+
+#: machine constants keyed by ``jax.Device.device_kind``
+CHIPS = {
+    "TPU v5 lite": Chip(
+        peak_flops=197e12,
+        vpu_ops=3.9e12,
+        hbm_bw=819e9,
+        ici_bw=50e9,
+        vmem_bytes=16 * 2**20,
+        source=(
+            "Google Cloud TPU v5e documentation: 197 TFLOP/s bf16, "
+            "819 GB/s HBM, 1,600 Gbit/s ICI (taken as 4 links of "
+            "50 GB/s). vpu_ops is derived (8x128 lanes x 4 ALUs), not "
+            "published; vmem_bytes is the compiler's default scoped limit"
+        ),
+    ),
+}
+
+#: the chip tiles are picked for where no TPU is attached (the CPU times
+#: nothing, but its tiles must be ones this chip's compiler accepts)
+DEFAULT_CHIP = "TPU v5 lite"
+
+
+@functools.lru_cache(maxsize=1)
+def chip() -> Chip:
+    """Constants of the chip this process computes on.
+
+    A TPU whose ``device_kind`` has no entry in :data:`CHIPS` raises
+    rather than being tuned with another chip's numbers; off-TPU the
+    :data:`DEFAULT_CHIP` entry applies.
+    """
+    import jax
+
+    dev = jax.devices()[0]
+    kind = dev.device_kind if dev.platform == "tpu" else DEFAULT_CHIP
+    if kind not in CHIPS:
+        raise ValueError(
+            f"no machine constants for device_kind {kind!r}; add an "
+            f"entry with its source to repro.kernels.autotune.CHIPS "
+            f"(known: {sorted(CHIPS)})"
+        )
+    return CHIPS[kind]
+
+
+# The modeled target of the stage-level rooflines: repro.launch
+# .analytics and repro.launch.dryrun import these, so the kernel tuner
+# and the pipeline cost model agree about the hardware they model.
+PEAK_FLOPS, VPU_OPS, HBM_BW, ICI_BW = CHIPS[DEFAULT_CHIP][:4]
+
+
+def vmem_budget() -> int:
+    """VMEM a kernel's pipelined working set (double-buffered streamed
+    inputs) may take on the chip in use (:func:`chip`): half the scoped
+    limit, leaving headroom for the compiler's own temporaries."""
+    return chip().vmem_bytes // 2
 
 ENV_TILES = "REPRO_MINPLUS_TILES"
 ENV_AUTOTUNE = "REPRO_MINPLUS_AUTOTUNE"
@@ -139,12 +194,13 @@ def modeled_cost(
     bm, bn, bk, unroll = clamp(cfg, m, n, k)
     seeded = op in FUSED_OPS
 
+    hw = chip()
     # compute: 2 VPU ops (add + min) per (i, j, k) triple, derated by
     # register fill and the extra running-min pass per rank-unroll step
     lane_fill = min(bn, 128) / 128.0
     sublane_fill = min(bm, 8) / 8.0
     unroll_eff = (2.0 * unroll) / (2.0 * unroll + 1.0)
-    eff_ops = VPU_OPS * lane_fill * sublane_fill * unroll_eff
+    eff_ops = hw.vpu_ops * lane_fill * sublane_fill * unroll_eff
     compute_s = (2.0 * m * n * k) / eff_ops
 
     # memory: contraction operands are re-fetched once per orthogonal
@@ -155,7 +211,7 @@ def modeled_cost(
         + m * n                    # output write
         + (m * n if seeded else 0)  # seed read
     )
-    hbm_s = hbm_bytes / (hbm_bw if hbm_bw else HBM_BW)
+    hbm_s = hbm_bytes / (hbm_bw if hbm_bw else hw.hbm_bw)
 
     # VMEM working set: a + b tiles (double-buffered while streaming),
     # accumulator + output tile (+ seed tile view), and the transient
@@ -174,24 +230,35 @@ def modeled_cost(
     )
 
 
-def _tile_sizes(dim: int, *, cap: int = 512) -> list[int]:
-    """Power-of-two tile sizes dividing ``dim`` (plus ``dim`` itself when
-    nothing else divides it, so odd shapes still get a config)."""
+def _tile_sizes(dim: int, *, cap: int = 512, align: int = 8) -> list[int]:
+    """Power-of-two tile sizes dividing ``dim`` that are multiples of
+    ``align`` (plus ``dim`` itself when it fits under ``cap`` or nothing
+    else divides it, so odd shapes still get a config).  The chip takes
+    a block dim that is a multiple of its (8, 128) register tiling or the
+    whole array dim: ``align`` is 8 for a sublane dim, 128 for a lane
+    dim."""
     sizes = [t for t in (8, 16, 32, 64, 128, 256, 512)
-             if t <= min(dim, cap) and dim % t == 0]
-    if not sizes or dim <= cap and dim not in sizes:
-        sizes.append(min(dim, cap) if dim % min(dim, cap) == 0 else dim)
+             if t % align == 0 and t <= min(dim, cap) and dim % t == 0]
+    if not sizes or dim <= cap:
+        sizes.append(dim)
     return sorted(set(sizes))
+
+
+#: largest row tile: the kernels' statically unrolled contraction keeps
+#: per-column broadcasts of the row tile live, and at 512 rows the chip's
+#: compiler runs out of scoped VMEM for some (bn, bk)
+MAX_BM = 256
 
 
 def candidates(m: int, n: int, k: int) -> Iterator[TileConfig]:
     """Enumerate valid tile configs for an (m, n, k) problem: power-of-two
-    tiles dividing each dim, unrolls dividing bk, VMEM budget respected.
-    The (clamped) static default is always included."""
+    tiles dividing each dim that the chip's compiler accepts (``bn`` and
+    ``bk`` are lane dims of some block), unrolls dividing bk.  The
+    (clamped) static default is included when it is such a tiling."""
     seen = set()
-    for bm in _tile_sizes(m):
-        for bn in _tile_sizes(n):
-            for bk in _tile_sizes(k):
+    for bm in _tile_sizes(m, cap=MAX_BM):
+        for bn in _tile_sizes(n, align=128):
+            for bk in _tile_sizes(k, align=128):
                 for unroll in (1, 2, 4, 8, 16):
                     if unroll > bk or bk % unroll:
                         continue
@@ -200,8 +267,18 @@ def candidates(m: int, n: int, k: int) -> Iterator[TileConfig]:
                         seen.add(cfg)
                         yield cfg
     dflt = clamp(DEFAULT, m, n, k)
-    if dflt not in seen and divides(dflt, m, n, k):
+    if dflt not in seen and divides(dflt, m, n, k) and _chip_legal(dflt, m, n, k):
         yield dflt
+
+
+def _chip_legal(cfg: TileConfig, m: int, n: int, k: int) -> bool:
+    """The (clamped) config is a tiling the chip's compiler accepts."""
+    c = clamp(cfg, m, n, k)
+    return (
+        c.bm <= MAX_BM and (c.bm % 8 == 0 or c.bm == m)
+        and (c.bn % 128 == 0 or c.bn == n)
+        and (c.bk % 128 == 0 or c.bk == k)
+    )
 
 
 @functools.lru_cache(maxsize=4096)
@@ -216,13 +293,14 @@ def best_config(
     ``launch_s`` rank under measured-corrected machine constants."""
     best = None
     fallback = None  # smallest-working-set candidate, if none fit budget
+    budget = vmem_budget()
     for cfg in candidates(m, n, k):
         cost = modeled_cost(op, m, n, k, cfg, itemsize=itemsize,
                             hbm_bw=hbm_bw, launch_s=launch_s)
         fkey = (cost.vmem_bytes, cost.time_s)
         if fallback is None or fkey < fallback[0]:
             fallback = (fkey, cfg, cost)
-        if cost.vmem_bytes > VMEM_BUDGET:
+        if cost.vmem_bytes > budget:
             continue
         # tie-break toward larger tiles (fewer grid steps, less refetch)
         key = (cost.time_s, (m // cfg.bm) * (n // cfg.bn) * (k // cfg.bk),
@@ -334,10 +412,9 @@ def tiles_for(op: str, m: int, n: int, k: int, *, itemsize: int = 4) -> dict:
 # Knobs of the sparse frontier-relaxation kernel (repro.kernels.frontier)
 # and its SSSP driver (repro.core.sparse.sssp_panel).  Unlike the min-plus
 # family, the tunables span two layers: ``bn`` is the kernel's node-tile
-# width, while ``bs`` (sources resident per launch) and ``bucket`` (masked
-# sweeps per convergence check) are driver-level — they are tuned together
-# because VMEM residency couples them: the whole (bs, n) distance block
-# stays resident across the node grid.
+# width, while ``bs`` (sources per launch) and ``bucket`` (masked sweeps
+# per convergence check) belong to sssp_panel — they are tuned together
+# because the per-sweep gathered (bs, deg, n) block couples them.
 
 ENV_FRONTIER_TILES = "REPRO_FRONTIER_TILES"
 ENV_FRONTIER_AUTOTUNE = "REPRO_FRONTIER_AUTOTUNE"
@@ -351,7 +428,7 @@ FRONTIER_SWEEPS_PRIOR = 32
 class FrontierConfig(NamedTuple):
     """Static knobs of one sparse-geodesic solve."""
 
-    bs: int      # landmark sources resident per kernel launch
+    bs: int      # landmark sources per kernel launch
     bn: int      # node columns per grid step
     bucket: int  # masked sweeps between convergence checks
 
@@ -368,8 +445,10 @@ def frontier_cost(
     check and the expected bucket-overshoot waste.
 
     Per sweep the VPU does 3 ops per (source, node, lane) triple (mask
-    select, add, running min); HBM moves the resident (bs, n) distances
-    in and out once plus the (n, deg) nbr/w pair.  The convergence check
+    select, add, running min); HBM moves the (bs, deg, n) gathered block
+    three times (the gather's reads and write, the kernel's read), the
+    (bs, n) distances in twice and out once, plus the (n, deg) nbr/w
+    pair.  The convergence check
     is an (bs, n) reduction charged once per ``bucket`` sweeps; overshoot
     charges the (bucket-1)/2 sweeps expected to run past the settle point,
     spread over :data:`FRONTIER_SWEEPS_PRIOR` productive sweeps.
@@ -379,14 +458,18 @@ def frontier_cost(
     amortizes the (n, deg) nbr/w stream over more sources.
     """
     bs, bn, bucket = cfg
-    bw = hbm_bw if hbm_bw else HBM_BW
+    hw = chip()
+    bw = hbm_bw if hbm_bw else hw.hbm_bw
     lane_fill = min(bn, 128) / 128.0
-    sublane_fill = min(bs, 8) / 8.0
-    compute_s = (3.0 * bs * n * deg) / (VPU_OPS * lane_fill * sublane_fill)
+    sublane_fill = min(deg, 8) / 8.0
+    compute_s = (3.0 * bs * n * deg) / (
+        hw.vpu_ops * lane_fill * sublane_fill
+    )
     hbm_bytes = itemsize * (
-        bs * n          # resident distance read
-        + 2 * n * deg   # nbr + w stream
-        + bs * n        # output write
+        3 * bs * deg * n  # gathered block: gather reads + write, kernel read
+        + 2 * bs * n      # distance reads (gather source, seed)
+        + 2 * n * deg     # nbr + w stream
+        + bs * n          # output write
     )
     hbm_s = hbm_bytes / bw
     sweep_s = max(compute_s, hbm_s) + launch_s
@@ -395,10 +478,10 @@ def frontier_cost(
         sweep_s * (1.0 + (bucket - 1) / (2.0 * FRONTIER_SWEEPS_PRIOR))
         + check_s / bucket
     ) / bs
-    # resident distances + double-buffered nbr/w tiles + the (bs, bn, deg)
-    # gather intermediate + current/output tiles
+    # double-buffered (bs, deg, bn) gathered tiles plus the masked copy,
+    # double-buffered w tiles, double-buffered seed + output tiles
     vmem = itemsize * (
-        bs * n + 2 * 2 * bn * deg + bs * bn * deg + 2 * bs * bn
+        3 * bs * deg * bn + 2 * deg * bn + 4 * bs * bn
     )
     return Cost(
         time_s=time_s,
@@ -410,11 +493,12 @@ def frontier_cost(
 
 
 def frontier_batch(n: int, m: int, *, itemsize: int = 4) -> int:
-    """Largest landmark-batch size whose resident (bs, n) distance block
-    leaves half the VMEM budget for tiles and the gather intermediate.
-    Single source of the residency bound the driver and the stage
+    """Largest power-of-two landmark batch (at most 64) whose (bs, n)
+    distance block is at most half the VMEM budget — which bounds the
+    gathered (bs, deg, n) block each sweep streams through HBM at deg
+    times that.  Single source of the batch cap sssp_panel and the stage
     segmentation both use (units = ceil(m / frontier_batch))."""
-    cap = max(1, (VMEM_BUDGET // 2) // max(1, n * itemsize))
+    cap = max(1, (chip().vmem_bytes // 4) // max(1, n * itemsize))
     bs = 1
     while bs * 2 <= min(cap, m, 64):
         bs *= 2
@@ -425,8 +509,9 @@ def frontier_candidates(
     n: int, deg: int, m: int
 ) -> Iterator[FrontierConfig]:
     """Enumerate frontier configs: power-of-two source batches up to the
-    residency cap, node tiles (ops.py pads n to a multiple, so no
-    divisibility constraint), buckets 1..16."""
+    batch cap, node tiles in multiples of 128, the chip's lane tiling
+    (ops.py pads n to a multiple, so no divisibility constraint), buckets
+    1..16."""
     bs_cap = frontier_batch(n, m)
     for bs in (1, 2, 4, 8, 16, 32, 64):
         if bs > bs_cap:
@@ -451,6 +536,7 @@ def best_frontier_config(
     best = None
     fallback = None
     seen = set()
+    budget = vmem_budget()
     dflt = FrontierConfig(
         min(FRONTIER_DEFAULT.bs, frontier_batch(n, m)),
         min(FRONTIER_DEFAULT.bn, n),
@@ -465,7 +551,7 @@ def best_frontier_config(
         fkey = (cost.vmem_bytes, cost.time_s)
         if fallback is None or fkey < fallback[0]:
             fallback = (fkey, cfg, cost)
-        if cost.vmem_bytes > VMEM_BUDGET:
+        if cost.vmem_bytes > budget:
             continue
         key = (cost.time_s, -cfg.bs, -cfg.bn)
         if best is None or key < best[0]:
@@ -489,7 +575,7 @@ def resolve_frontier_config(
 
     1. ``REPRO_FRONTIER_TILES=bs,bn,bucket`` — pinned.
     2. ``REPRO_FRONTIER_AUTOTUNE=0`` — the static default, batch clamped
-       to the VMEM residency cap.
+       to :func:`frontier_batch`.
     3. The measured-calibration layer (persisted winner / fresh sweep /
        corrected-constant re-rank).
     4. Otherwise the cached analytic sweep
@@ -531,9 +617,6 @@ def frontier_config(n: int, deg: int, m: int) -> FrontierConfig:
 ENV_KNN_TILES = "REPRO_KNN_TILES"
 ENV_KNN_AUTOTUNE = "REPRO_KNN_AUTOTUNE"
 
-#: f32 matmul throughput on the MXU (half the bf16 peak)
-MXU_F32_FLOPS = PEAK_FLOPS / 2
-
 
 class KnnConfig(NamedTuple):
     """Static tile knobs of one fused kNN kernel launch."""
@@ -560,16 +643,17 @@ def knn_cost(
     seed-read / output-write of the (m, k) lists — the distance tile
     itself never reaches HBM, which is the point of the fusion.
     """
+    hw = chip()
     bm, bn = min(cfg.bm, m), min(cfg.bn, n)
     mp = -(-m // bm) * bm       # padded problem dims (ops.knn_topk pads)
     np_ = -(-n // bn) * bn
     gm, gn = mp // bm, np_ // bn
 
-    matmul_s = (2.0 * mp * np_ * d) / MXU_F32_FLOPS
+    matmul_s = (2.0 * mp * np_ * d) / (hw.peak_flops / 2)
     lane_fill = min(bn + k, 128) / 128.0
     sublane_fill = min(bm, 8) / 8.0
     select_s = (6.0 * k * (bn + k) * bm * gm * gn) / (
-        VPU_OPS * lane_fill * sublane_fill
+        hw.vpu_ops * lane_fill * sublane_fill
     )
     compute_s = matmul_s + select_s
 
@@ -579,7 +663,7 @@ def knn_cost(
         + 2 * mp * k       # seed lists read (dists + indices)
         + 2 * mp * k       # output lists write
     )
-    hbm_s = hbm_bytes / (hbm_bw if hbm_bw else HBM_BW)
+    hbm_s = hbm_bytes / (hbm_bw if hbm_bw else hw.hbm_bw)
 
     # VMEM: double-buffered point tiles, the distance tile, the
     # (bm, bn + k) vals/idxs/pos merge working set, running + output lists
@@ -607,10 +691,12 @@ def _pow2_tiles(dim: int, *, cap: int = 512) -> list[int]:
 
 def knn_candidates(m: int, n: int, k: int) -> Iterator[KnnConfig]:
     """Enumerate fused-kNN tile configs; the clamped static default is
-    always included so the winner never models slower than it."""
+    always included so the winner never models slower than it.  ``bn``
+    is a lane dim of the kernel's (1, bn) norm block, so it is a multiple
+    of 128 or, for n < 128, the whole dim."""
     seen = set()
     for bm in _pow2_tiles(m):
-        for bn in _pow2_tiles(n):
+        for bn in [t for t in _pow2_tiles(n) if t % 128 == 0] or [n]:
             cfg = KnnConfig(bm, bn)
             if cfg not in seen:
                 seen.add(cfg)
@@ -630,13 +716,14 @@ def best_knn_config(
     ``hbm_bw``/``launch_s`` rank under measured-corrected constants."""
     best = None
     fallback = None
+    budget = vmem_budget()
     for cfg in knn_candidates(m, n, k):
         cost = knn_cost(m, n, d, k, cfg, itemsize=itemsize,
                         hbm_bw=hbm_bw, launch_s=launch_s)
         fkey = (cost.vmem_bytes, cost.time_s)
         if fallback is None or fkey < fallback[0]:
             fallback = (fkey, cfg, cost)
-        if cost.vmem_bytes > VMEM_BUDGET:
+        if cost.vmem_bytes > budget:
             continue
         # tie-break toward larger tiles (fewer grid passes, less refetch)
         key = (cost.time_s, -(cfg.bm * cfg.bn))
@@ -690,21 +777,19 @@ def knn_config(m: int, n: int, d: int, k: int) -> KnnConfig:
 
 
 def pairwise_tiles(m: int, n: int, d: int, *, cap: int = 512) -> dict:
-    """Largest dividing tiles for the (non-fused) pairwise kernel — the
-    auto-shrink path :func:`repro.kernels.ops.pairwise_sq_dists` takes
-    when no explicit tiles are given, so shapes the static 512 defaults
-    do not divide shrink to a legal tiling instead of crashing on the
-    kernel's divisibility assert."""
-    return {
-        "bm": _tile_sizes(m, cap=cap)[-1],
-        "bn": _tile_sizes(n, cap=cap)[-1],
-        "bd": _tile_sizes(d, cap=cap)[-1],
-    }
+    """Tiles for the (non-fused) pairwise kernel — the path
+    :func:`repro.kernels.ops.pairwise_sq_dists` takes when no explicit
+    tiles are given.  A dim up to ``cap`` is one whole-dim tile; a longer
+    one gets ``cap``-wide tiles (a multiple of the chip's (8, 128) register
+    tiling) and the wrapper pads it to a tile multiple, so every shape
+    gets a tiling the chip's compiler accepts."""
+    return {"bm": min(m, cap), "bn": min(n, cap), "bd": min(d, cap)}
 
 
 def clear_cache() -> None:
     """Drop the in-process sweep caches AND the measured layer's
     store-backed caches (tests / constant or store hot-swapping)."""
+    chip.cache_clear()
     best_config.cache_clear()
     best_frontier_config.cache_clear()
     best_knn_config.cache_clear()

@@ -15,19 +15,15 @@ above ``hi`` are not allowed to propagate this sweep, which is both the
 delta-stepping bucket discipline and the mask that keeps half-settled
 long-range values from being charged as settled.
 
-Layout: the whole (s, n) distance block stays resident in VMEM (constant
-index map) because every node tile gathers from arbitrary columns; the
-grid runs over node tiles only.  The driver
-(:func:`repro.core.sparse.sssp_panel`) keeps ``s`` small enough that
-``s * n`` floats fit the budget — :func:`repro.kernels.autotune
-.frontier_batch` is the single source of that bound.  The gather is a
-``jnp.take`` from the resident block; on TPU this lowers to a dynamic
-gather, which Mosaic supports for VMEM-resident operands (off TPU the
-kernel runs in interpret mode where the gather is ordinary XLA).
-
-The kernel jits once per (s, n, deg, bn) shape: the driver pads frontiers
-to fixed shape so bucket progression never recompiles, and ``hi`` enters
-as a (1, 1) array operand rather than a static constant.
+Layout: the gather ``D[q, nbr[j, d]]`` runs in XLA ahead of the kernel,
+into a lane-dense (s, deg, n) block (node index on the lanes); the TPU
+lowering has no general in-VMEM gather.  The kernel then streams that
+block, the (deg, n) transposed weights and the (s, n) seed distances
+through node tiles of width ``bn`` (a multiple of 128 on the chip) and
+does the mask, the relax and both mins.  ``hi`` is a scalar operand in
+SMEM rather than a static constant, and sssp_panel pads frontiers to
+fixed shape, so the kernel jits once per (s, n, deg, bn) shape and
+bucket progression never recompiles.
 """
 from __future__ import annotations
 
@@ -36,43 +32,19 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-def _tpu_compiler_params():
-    """dimension_semantics for the 1-D node-tile grid (None off-TPU).
-
-    Mirrors :func:`repro.kernels.minplus._tpu_compiler_params` but with a
-    single parallel grid dimension — every node tile is independent."""
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-
-        cls = getattr(pltpu, "CompilerParams", None) or getattr(
-            pltpu, "TPUCompilerParams", None
-        )
-        if cls is not None:
-            return cls(dimension_semantics=("parallel",))
-    except ImportError:
-        pass
-    return None
+from jax.experimental.pallas import tpu as pltpu
 
 
-def _frontier_kernel(hi_ref, dist_ref, nbr_ref, w_ref, o_ref):
-    hi = hi_ref[0, 0]
-    dist = dist_ref[...]            # (s, n), resident across the grid
-    idx = nbr_ref[...]              # (bn, deg)
-    wt = w_ref[...]                 # (bn, deg)
-    s = dist.shape[0]
-    bn, deg = idx.shape
-
-    # gather -> threshold mask -> relax -> seed-min, in this exact order;
-    # the CSR oracle (ref.frontier_relax_ref) replays the same sequence so
-    # results are bit-identical (min is exact, add is one rounding per
-    # term in both).
-    g = jnp.take(dist, idx.reshape(-1), axis=1).reshape(s, bn, deg)
+def _frontier_kernel(hi_ref, g_ref, w_ref, d_ref, o_ref):
+    # threshold mask -> relax -> min over each node's neighbour lanes ->
+    # seed min, in this exact order; the CSR oracle (ref.frontier_relax_ref)
+    # replays the same sequence so results are bit-identical (min is
+    # exact, add is one rounding per term in both)
+    hi = hi_ref[0]
+    g = g_ref[...]                                  # (s, deg, bn)
     g = jnp.where(g < hi, g, jnp.inf)
-    cand = jnp.min(g + wt[None, :, :], axis=2)          # (s, bn)
-    j = pl.program_id(0)
-    cur = jax.lax.dynamic_slice(dist, (0, j * bn), (s, bn))
-    o_ref[...] = jnp.minimum(cur, cand)
+    cand = jnp.min(g + w_ref[...][None, :, :], axis=1)  # (s, bn)
+    o_ref[...] = jnp.minimum(d_ref[...], cand)
 
 
 @functools.partial(jax.jit, static_argnames=("bn", "interpret"))
@@ -99,20 +71,22 @@ def frontier_relax(
         f"n={n} not divisible by node tile bn={bn} "
         "(ops.frontier_relax pads to a tile multiple)"
     )
-    hi = jnp.asarray(hi, dist.dtype).reshape(1, 1)
+    hi = jnp.asarray(hi, dist.dtype).reshape(1)
+    g = jnp.take(dist, nbr.T, axis=1)               # (s, deg, n)
 
-    grid = (n // bn,)
     return pl.pallas_call(
         _frontier_kernel,
-        grid=grid,
+        grid=(n // bn,),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda j: (0, 0)),
-            pl.BlockSpec((s, n), lambda j: (0, 0)),
-            pl.BlockSpec((bn, deg), lambda j: (j, 0)),
-            pl.BlockSpec((bn, deg), lambda j: (j, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec((s, deg, bn), lambda j: (0, 0, j)),
+            pl.BlockSpec((deg, bn), lambda j: (0, j)),
+            pl.BlockSpec((s, bn), lambda j: (0, j)),
         ],
         out_specs=pl.BlockSpec((s, bn), lambda j: (0, j)),
         out_shape=jax.ShapeDtypeStruct((s, n), dist.dtype),
-        compiler_params=_tpu_compiler_params(),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)
+        ),
         interpret=interpret,
-    )(hi, dist, nbr, w)
+    )(hi, g, w.T, dist)

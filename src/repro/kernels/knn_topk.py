@@ -19,24 +19,27 @@ composable — `knn_blocked` seeds with (+inf, -1) empty lists, `knn_ring`
 seeds each ring step with the previous step's lists, and a streaming
 caller could seed with candidates from an earlier shard of columns.
 
-Selection rule ("first wins"): candidates are ranked by (distance, then
-position in the stream), where the stream is [running list | tile columns
-in ascending index order].  This is exactly the tie-break ``lax.top_k``
-documents (lower index first on equal values), which makes the result
-independent of the (bm, bn) tiling — a tie at the k-boundary is always won
-by the smaller global column index because column tiles arrive in
-ascending order — and bit-identical to the chunked oracle
-(:func:`repro.kernels.ref.knn_topk_ref`) for any chunking: min and
-compare are exact, and the distance tile is computed with the identical
+Selection rule: candidates are ranked by (distance, then global column
+index), so a tie is won by the smaller column whatever order the columns
+arrive in — by tile, by seeded call, or by ring step.  That makes the
+result independent of the (bm, bn) tiling and of the chaining order (a
+ring over a mesh returns the one-chip lists), and bit-identical to the
+chunked oracle (:func:`repro.kernels.ref.knn_topk_ref`) for any chunking:
+min and compare are exact, and the distance tile is computed with the
+identical
 x2 + y2 - 2<x,y> op sequence over the full feature depth in both.
 
-Masking is done in-kernel from a (1, 3) int32 operand (row0, col0, hi):
+The squared row norms come in as operands, computed once per call by
+the wrapper (:func:`repro.kernels.ref.sq_norms`), so kernel and oracle
+add the very same norms.
+
+Masking is done in-kernel from a (3,) int32 SMEM operand (row0, col0, hi):
 a lane is dead when its global column equals its global row (self-match)
 or is >= hi (padded columns / columns beyond the caller's valid range).
 Dead lanes carry (+inf, -1); rows with fewer than k live candidates
 return (+inf, -1) in the unfilled slots.  The offsets are traced array
-operands (constant index map, like the frontier kernel's ``hi``) so ring
-steps with varying owners do not recompile.
+operands (like the frontier kernel's ``hi``) so ring steps with varying
+owners do not recompile.
 """
 from __future__ import annotations
 
@@ -45,30 +48,17 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.ref import DOT_PRECISION
 
 #: index carried by masked / unfilled candidate slots
 PAD_IDX = -1
 
 
-def _tpu_compiler_params():
-    """dimension_semantics for the (rows, columns) grid (None off-TPU):
-    row tiles are independent, column tiles accumulate sequentially into
-    the revisited candidate list — same shape as minplus_update's
-    contraction dimension."""
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-
-        cls = getattr(pltpu, "CompilerParams", None) or getattr(
-            pltpu, "TPUCompilerParams", None
-        )
-        if cls is not None:
-            return cls(dimension_semantics=("parallel", "arbitrary"))
-    except ImportError:
-        pass
-    return None
-
-
-def _knn_topk_kernel(meta_ref, x_ref, y_ref, sd_ref, si_ref, od_ref, oi_ref):
+def _knn_topk_kernel(
+    meta_ref, x_ref, y_ref, x2_ref, y2_ref, sd_ref, si_ref, od_ref, oi_ref
+):
     j = pl.program_id(1)
 
     @pl.when(j == 0)
@@ -76,9 +66,9 @@ def _knn_topk_kernel(meta_ref, x_ref, y_ref, sd_ref, si_ref, od_ref, oi_ref):
         od_ref[...] = sd_ref[...]
         oi_ref[...] = si_ref[...]
 
-    row0 = meta_ref[0, 0]
-    col0 = meta_ref[0, 1]
-    hi = meta_ref[0, 2]
+    row0 = meta_ref[0]
+    col0 = meta_ref[1]
+    hi = meta_ref[2]
 
     x = x_ref[...].astype(jnp.float32)  # (bm, D)
     y = y_ref[...].astype(jnp.float32)  # (bn, D)
@@ -86,16 +76,15 @@ def _knn_topk_kernel(meta_ref, x_ref, y_ref, sd_ref, si_ref, od_ref, oi_ref):
     k = od_ref.shape[1]
 
     # one (bm, bn) distance tile on the MXU — same op sequence as the
-    # pairwise kernel / oracle: x2 + y2 - 2<x,y> over the full feature
-    # depth, clamped at zero (one rounding per term, so bit-identical)
-    x2 = jnp.sum(x * x, axis=1, keepdims=True)          # (bm, 1)
-    y2 = jnp.sum(y * y, axis=1, keepdims=True)          # (bn, 1)
+    # oracle: x2 + y2 - 2<x,y> over the full feature depth, clamped at
+    # zero (one rounding per term, so bit-identical)
     xy = jax.lax.dot_general(
         x, y,
         dimension_numbers=(((1,), (1,)), ((), ())),
+        precision=DOT_PRECISION,
         preferred_element_type=jnp.float32,
     )
-    d = jnp.maximum(x2 + y2.T - 2.0 * xy, 0.0)
+    d = jnp.maximum(x2_ref[...] + y2_ref[...] - 2.0 * xy, 0.0)
 
     i = pl.program_id(0)
     rows = row0 + i * bm + jax.lax.broadcasted_iota(jnp.int32, (bm, bn), 0)
@@ -106,8 +95,8 @@ def _knn_topk_kernel(meta_ref, x_ref, y_ref, sd_ref, si_ref, od_ref, oi_ref):
 
     # merge the tile into the running list: k extraction steps over the
     # (bm, k + bn) candidate stream [running list | tile columns].  Each
-    # step takes the (value, stream position)-minimum — "first wins" on
-    # ties, the lax.top_k tie-break — then retires that position.
+    # step takes the (value, column index)-minimum, then retires its
+    # position (the first one, should a column come twice).
     vals = jnp.concatenate([od_ref[...], d], axis=1)    # (bm, k + bn)
     idxs = jnp.concatenate([oi_ref[...], idx], axis=1)
     width = k + bn
@@ -117,16 +106,20 @@ def _knn_topk_kernel(meta_ref, x_ref, y_ref, sd_ref, si_ref, od_ref, oi_ref):
     def step(t, carry):
         vals, pos, out_d, out_i = carry
         v = jnp.min(vals, axis=1, keepdims=True)        # (bm, 1)
-        tie = vals == v
-        # retired positions carry pos = width, so p < width always (at
-        # step t < k at most t < width positions are retired) and sel
-        # picks exactly one live position per row
-        p = jnp.min(jnp.where(tie, pos, width), axis=1, keepdims=True)
-        sel = pos == p
+        # retired positions carry pos = width and take no part; at step
+        # t < k at most t < width are retired, so each row has a live
+        # tie and sel picks exactly one live position.  Once only +inf
+        # is left, the live entries are dead lanes / empty seed slots,
+        # all with index PAD_IDX
+        tie = (vals == v) & (pos < width)
         iv = jnp.min(
-            jnp.where(sel, idxs, jnp.iinfo(jnp.int32).max),
+            jnp.where(tie, idxs, jnp.iinfo(jnp.int32).max),
             axis=1, keepdims=True,
         )
+        p = jnp.min(
+            jnp.where(tie & (idxs == iv), pos, width), axis=1, keepdims=True
+        )
+        sel = pos == p
         out_d = jnp.where(lane == t, v, out_d)
         out_i = jnp.where(lane == t, iv, out_i)
         return (
@@ -149,6 +142,8 @@ def _knn_topk_kernel(meta_ref, x_ref, y_ref, sd_ref, si_ref, od_ref, oi_ref):
 def knn_topk(
     x: jax.Array,
     y: jax.Array,
+    x2: jax.Array,
+    y2: jax.Array,
     seed_d: jax.Array,
     seed_i: jax.Array,
     meta: jax.Array,
@@ -159,10 +154,12 @@ def knn_topk(
 ) -> tuple[jax.Array, jax.Array]:
     """Fused k-nearest merge of y's rows into x's candidate lists.
 
-    x (m, D), y (n, D), seed_d/seed_i (m, k), meta (1, 3) int32
-    [row0, col0, hi] -> (dists (m, k) f32, idx (m, k) int32), sorted by
-    (distance, arrival).  ``m``/``n`` must be tile multiples —
-    :func:`repro.kernels.ops.knn_topk` pads and strips.
+    x (m, D), y (n, D), their squared row norms x2 (m, 1) and y2 (1, n),
+    seed_d/seed_i (m, k), meta (3,) int32 [row0, col0, hi] ->
+    (dists (m, k) f32, idx (m, k) int32), sorted by (distance, column).
+    ``m``/``n`` must be tile multiples — :func:`repro.kernels.ops
+    .knn_topk` pads and strips.  On the chip ``bn`` is a multiple of 128
+    or the whole (padded) ``n``.
     """
     m, dfeat = x.shape
     n, d2 = y.shape
@@ -181,9 +178,11 @@ def knn_topk(
         _knn_topk_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 3), lambda i, j: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((bm, dfeat), lambda i, j: (i, 0)),
             pl.BlockSpec((bn, dfeat), lambda i, j: (j, 0)),
+            pl.BlockSpec((bm, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((1, bn), lambda i, j: (0, j)),
             pl.BlockSpec((bm, k), lambda i, j: (i, 0)),
             pl.BlockSpec((bm, k), lambda i, j: (i, 0)),
         ],
@@ -195,6 +194,11 @@ def knn_topk(
             jax.ShapeDtypeStruct((m, k), jnp.float32),
             jax.ShapeDtypeStruct((m, k), jnp.int32),
         ),
-        compiler_params=_tpu_compiler_params(),
+        # row tiles are independent; column tiles accumulate sequentially
+        # into the revisited candidate list
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")
+        ),
         interpret=interpret,
-    )(meta, x, y, seed_d.astype(jnp.float32), seed_i.astype(jnp.int32))
+    )(meta, x, y, x2, y2, seed_d.astype(jnp.float32),
+      seed_i.astype(jnp.int32))

@@ -196,7 +196,7 @@ def save_store(store: dict, path: str | None = None) -> str:
 
 
 def device_kind() -> str:
-    """The current device's kind string (e.g. ``"cpu"``, ``"TPU v5e"``) —
+    """The current device's kind string (e.g. ``"cpu"``, ``"TPU v5 lite"``) —
     the store's per-chip-generation key."""
     import jax
 
@@ -242,7 +242,7 @@ def fit_constants(samples) -> dict:
     samples = [s for s in samples if len(s) == 3 and s[0] > 0 and s[2] > 0]
     if len(samples) < 2:
         return {
-            "hbm_bw": float(autotune.HBM_BW),
+            "hbm_bw": float(autotune.chip().hbm_bw),
             "launch_s": 0.0,
             "n_samples": len(samples),
         }
@@ -252,7 +252,7 @@ def fit_constants(samples) -> dict:
     if not np.isfinite(inv_bw) or inv_bw <= 0:
         # all-launch-dominated or degenerate: keep the analytic bandwidth
         return {
-            "hbm_bw": float(autotune.HBM_BW),
+            "hbm_bw": float(autotune.chip().hbm_bw),
             "launch_s": max(float(np.median(y)), 0.0),
             "n_samples": len(samples),
         }
@@ -342,7 +342,7 @@ def _top_minplus(op, m, n, k, itemsize):
     ranked = []
     for cfg in autotune.candidates(m, n, k):
         cost = autotune.modeled_cost(op, m, n, k, cfg, itemsize=itemsize)
-        if cost.vmem_bytes > autotune.VMEM_BUDGET:
+        if cost.vmem_bytes > autotune.vmem_budget():
             continue
         ranked.append((cost.time_s, cfg, cost))
     ranked.sort(key=lambda t: t[0])
@@ -583,7 +583,7 @@ def calibrate_frontier(
         ranked = []
         for cfg in autotune.frontier_candidates(n, deg, m):
             cost = autotune.frontier_cost(n, deg, cfg, itemsize=itemsize)
-            if cost.vmem_bytes > autotune.VMEM_BUDGET:
+            if cost.vmem_bytes > autotune.vmem_budget():
                 continue
             ranked.append((cost.time_s, cfg, cost))
         ranked.sort(key=lambda t: t[0])
@@ -626,7 +626,7 @@ def calibrate_frontier(
             # amortization (check cost + expected overshoot), as in
             # autotune.frontier_cost but with the sweep term measured
             t_sweep = sweep_times[key]
-            check_s = itemsize * cfg.bs * n / autotune.HBM_BW
+            check_s = itemsize * cfg.bs * n / autotune.chip().hbm_bw
             t = (
                 t_sweep
                 * (1.0 + (cfg.bucket - 1)
@@ -685,7 +685,7 @@ def calibrate_knn(
         ranked = []
         for cfg in autotune.knn_candidates(m, n, k):
             cost = autotune.knn_cost(m, n, d, k, cfg, itemsize=itemsize)
-            if cost.vmem_bytes > autotune.VMEM_BUDGET:
+            if cost.vmem_bytes > autotune.vmem_budget():
                 continue
             ranked.append((cost.time_s, cfg, cost))
         ranked.sort(key=lambda t: t[0])

@@ -302,9 +302,11 @@ def pairwise_sq_dists(x, y, *, mode: str = "auto", **tile_kw):
     Tiles: explicit ``bm``/``bn``/``bd`` kwargs win and are validated up
     front (a non-dividing override raises a ``ValueError`` naming the
     shapes and tiles instead of surfacing as the kernel's raw assert);
-    otherwise the tiles auto-shrink to the largest dividing sizes
-    (:func:`repro.kernels.autotune.pairwise_tiles`), so arbitrary shapes
-    run on the Pallas path without the caller tiling by hand.
+    otherwise :func:`repro.kernels.autotune.pairwise_tiles` picks tiles
+    the chip accepts and the operands are zero-padded to tile multiples
+    (zero rows are stripped from the result; zero features add exact
+    zeros), so arbitrary shapes run on the Pallas path without the
+    caller tiling by hand.
     """
     m, d = x.shape
     n, d2 = y.shape
@@ -327,25 +329,26 @@ def pairwise_sq_dists(x, y, *, mode: str = "auto", **tile_kw):
     auto = autotune.pairwise_tiles(m, n, d)
     tiles = {**auto, **{k_: v for k_, v in tile_kw.items()
                         if k_ not in unknown and k_ not in bad_vals}}
-    if tile_kw:
-        bm = min(tiles["bm"], m)
-        bn = min(tiles["bn"], n)
-        bd = min(tiles["bd"], d)
-        if m % bm:
-            problems.append(f"bm={bm} does not divide m={m}")
-        if n % bn:
-            problems.append(f"bn={bn} does not divide n={n}")
-        if d % bd:
-            problems.append(f"bd={bd} does not divide D={d}")
+    for key, dim, name in (("bm", m, "m"), ("bn", n, "n"), ("bd", d, "D")):
+        tiles[key] = min(tiles[key], dim)
+        if key in tile_kw and key not in bad_vals and dim % tiles[key]:
+            problems.append(
+                f"{key}={tiles[key]} does not divide {name}={dim}"
+            )
     if problems:
         raise ValueError(
             f"pairwise_sq_dists: invalid tile override for "
             f"({m}, {d})x({n}, {d}): " + "; ".join(problems)
         )
     use_pallas, interpret = _resolve(mode)
-    if use_pallas:
-        return _pd_pallas(x, y, interpret=interpret, **tiles)
-    return _ref.pairwise_sq_dists_ref(x, y)
+    if not use_pallas:
+        return _ref.pairwise_sq_dists_ref(x, y)
+    pm, pn, pd = -m % tiles["bm"], -n % tiles["bn"], -d % tiles["bd"]
+    if pm or pn or pd:
+        x = jnp.pad(x, ((0, pm), (0, pd)))
+        y = jnp.pad(y, ((0, pn), (0, pd)))
+    out = _pd_pallas(x, y, interpret=interpret, **tiles)
+    return out[:m, :n] if pm or pn else out
 
 
 def knn_topk(
@@ -370,7 +373,7 @@ def knn_topk(
     steps.  Columns at or beyond ``n_valid`` (a global count, default
     ``col0 + n``; traced values fine) and each row's self-match are
     masked to (+inf, -1) in-kernel.  Returns (dists (m, k) f32,
-    idx (m, k) int32) ranked by (distance, then arrival order); rows
+    idx (m, k) int32) ranked by (distance, then column index); rows
     with fewer than k live candidates carry (+inf, -1) tails.
 
     Tiles: explicit ``bm``/``bn`` kwargs win (any positive size — the
@@ -421,10 +424,14 @@ def knn_topk(
         bm = min(tile_kw.get("bm", cfg.bm), m)
         bn = min(tile_kw.get("bn", cfg.bn), n)
 
+    # one set of norms per call, for whichever path runs (see
+    # ref.sq_norms for why neither path recomputes them)
+    x2 = _ref.sq_norms(x)
+    y2 = _ref.sq_norms(y)
     use_pallas, interpret = _resolve(mode)
     if not use_pallas:
         return _ref.knn_topk_ref(
-            x, y, seed_d, seed_i,
+            x, y, seed_d, seed_i, x2, y2,
             row0=row0, col0=col0, n_valid=n_valid, chunk=bn,
         )
 
@@ -435,22 +442,24 @@ def knn_topk(
     hi = c0 + n if n_valid is None else jnp.minimum(
         c0 + n, jnp.asarray(n_valid, jnp.int32)
     )
-    meta = jnp.stack(
-        [jnp.asarray(row0, jnp.int32), c0, hi]
-    ).reshape(1, 3)
+    meta = jnp.stack([jnp.asarray(row0, jnp.int32), c0, hi])
+    y2 = y2.T
     seed_d = seed_d.astype(jnp.float32)
     seed_i = seed_i.astype(jnp.int32)
     pm, pn = -m % bm, -n % bn
     if pm:
         x = jnp.pad(x, ((0, pm), (0, 0)))
+        x2 = jnp.pad(x2, ((0, pm), (0, 0)))
         seed_d = jnp.pad(seed_d, ((0, pm), (0, 0)),
                          constant_values=jnp.inf)
         seed_i = jnp.pad(seed_i, ((0, pm), (0, 0)),
                          constant_values=PAD_IDX)
     if pn:
         y = jnp.pad(y, ((0, pn), (0, 0)))
+        y2 = jnp.pad(y2, ((0, 0), (0, pn)))
     out_d, out_i = _kt_pallas(
-        x, y, seed_d, seed_i, meta, bm=bm, bn=bn, interpret=interpret
+        x, y, x2, y2, seed_d, seed_i, meta, bm=bm, bn=bn,
+        interpret=interpret,
     )
     return (out_d[:m], out_i[:m]) if pm else (out_d, out_i)
 
@@ -496,8 +505,8 @@ def auto_split_panels(
     saved = (
         2.0 * b * b * nc * (1.0 - 1.0 / pd)
         + 2.0 * b * b * nr * (1.0 - 1.0 / pm)
-    ) / autotune.VPU_OPS
+    ) / autotune.chip().vpu_ops
     gather = itemsize * (
         b * nc * (pd - 1) / pd + nr * b * (pm - 1) / pm
-    ) / autotune.ICI_BW
+    ) / autotune.chip().ici_bw
     return saved > gather

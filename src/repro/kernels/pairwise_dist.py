@@ -15,6 +15,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.ref import DOT_PRECISION
+
 
 def _pd_kernel(x_ref, y_ref, o_ref, *, last_step: int):
     @pl.when(pl.program_id(2) == 0)
@@ -28,6 +30,7 @@ def _pd_kernel(x_ref, y_ref, o_ref, *, last_step: int):
     xy = jax.lax.dot_general(                           # MXU: (bm, bn)
         x, y,
         dimension_numbers=(((1,), (1,)), ((), ())),
+        precision=DOT_PRECISION,
         preferred_element_type=jnp.float32,
     )
     o_ref[...] += x2 + y2.T - 2.0 * xy

@@ -13,6 +13,11 @@ import functools
 import jax
 import jax.numpy as jnp
 
+#: precision of every f32 distance product (kernels and oracles alike):
+#: left to the backend, a TPU computes an f32 dot in reduced precision
+#: and XLA and the kernel compiler need not reduce it the same way
+DOT_PRECISION = jax.lax.Precision.HIGHEST
+
 
 def minplus_ref(a: jax.Array, b: jax.Array, *, chunk: int = 256) -> jax.Array:
     """Tropical (min-plus) matrix product: C[i,j] = min_k A[i,k] + B[k,j].
@@ -189,8 +194,26 @@ def pairwise_sq_dists_ref(x: jax.Array, y: jax.Array) -> jax.Array:
     y = y.astype(jnp.float32)
     x2 = jnp.sum(x * x, axis=1, keepdims=True)
     y2 = jnp.sum(y * y, axis=1, keepdims=True)
-    d = x2 + y2.T - 2.0 * (x @ y.T)
-    return jnp.maximum(d, 0.0)
+    xy = jax.lax.dot_general(
+        x, y, dimension_numbers=(((1,), (1,)), ((), ())),
+        precision=DOT_PRECISION, preferred_element_type=jnp.float32,
+    )
+    return jnp.maximum(x2 + y2.T - 2.0 * xy, 0.0)
+
+
+@jax.jit
+def sq_norms(x: jax.Array) -> jax.Array:
+    """Squared row norms (m, 1) f32 of the kNN kernel's operands.
+
+    :func:`repro.kernels.ops.knn_topk` computes them once per call and
+    hands the same array to the kernel or to :func:`knn_topk_ref`: a
+    row's sum can round differently in two programs (XLA's CPU backend
+    orders a reduction by the row's position in the array, and contracts
+    a fused multiply-add chain in some fusions and not others), so norms
+    recomputed inside each program need not agree bit for bit.
+    """
+    x = x.astype(jnp.float32)
+    return jnp.sum(x * x, axis=1, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("k",))
@@ -200,17 +223,22 @@ def topk_smallest_ref(d: jax.Array, k: int):
     return -neg, idx
 
 
-# jitted as one program (not op-by-op): bit-identity with the kernel
-# needs XLA to make the same fma-contraction choices for the cancelling
-# x2 + y2 - 2xy combine, and those are per-compilation — an eagerly
-# dispatched x2 can round differently from the same op fused into the
-# kernel's program
+def topk_by_index(d: jax.Array, i: jax.Array, k: int):
+    """The k smallest (distance, index) pairs of each row, ranked by
+    distance and then index: the kNN lists' tie-break, which does not
+    depend on the order the candidates are laid out in."""
+    sd, si = jax.lax.sort((d, i), dimension=1, num_keys=2)
+    return sd[:, :k], si[:, :k]
+
+
 @functools.partial(jax.jit, static_argnames=("chunk",))
 def knn_topk_ref(
     x: jax.Array,
     y: jax.Array,
     seed_d: jax.Array,
     seed_i: jax.Array,
+    x2: jax.Array,
+    y2: jax.Array,
     *,
     row0=0,
     col0=0,
@@ -220,23 +248,25 @@ def knn_topk_ref(
     """Chunked oracle of the fused top-k kNN kernel
     (:func:`repro.kernels.knn_topk.knn_topk`).
 
-    x (m, D) query rows at global offset ``row0``; y (n, D) candidate
+    x (m, D) query rows at global offset ``row0``, with squared norms
+    x2 (m, 1); y (n, D) candidate rows, with squared norms y2 (n, 1) (both
+    from :func:`sq_norms`), at global
     rows at global column offset ``col0``; seed_d/seed_i (m, k) the
     incoming candidate lists ((+inf, -1) when empty).  Columns at or
     beyond ``n_valid`` (global count, default ``col0 + n``) are masked,
     as is each row's self-match.  Returns (dists, idx), each (m, k),
-    ranked by (distance, then arrival order) — the stream is
-    [seed list | columns ascending], so ties at the k-boundary go to the
-    earlier seed entry / smaller column index.
+    ranked by (distance, then column index) (:func:`topk_by_index`), so
+    a tie goes to the smaller column whether it came in the seed list or
+    in ``y``.
 
     Bit-identical to the Pallas kernel for any (chunk vs bm/bn) tiling:
-    the distance tile replays the kernel's exact op sequence
-    (full-depth MXU product, x2 + y2 - 2xy, clamp at zero — min/compare
-    are exact, one rounding per add), and the per-chunk
-    ``lax.top_k(-cat)`` fold implements the same (value, position)
-    selection the kernel's k-step extraction does: stable first-wins
-    selection over an ordered stream is prefix-stable, so folding in any
-    chunk size yields the whole-stream answer.
+    both are handed the same row norms, the distance tile replays the kernel's exact op sequence
+    (full-depth product at :data:`DOT_PRECISION`, x2 + y2 - 2xy, clamp at
+    zero — min/compare are exact, one rounding per add), and the per-chunk
+    fold (the chunk's own top k, then :func:`topk_by_index` with the
+    running list) makes the (value, index) selection the kernel's k-step
+    extraction does: selection under a total order is prefix-stable, so
+    folding in any chunk size yields the whole-stream answer.
     """
     m, dfeat = x.shape
     n, d2 = y.shape
@@ -252,9 +282,9 @@ def knn_topk_ref(
     chunk = min(chunk, n)
     pad = -n % chunk
     y_p = jnp.pad(y, ((0, pad), (0, 0))) if pad else y
+    y2_p = jnp.pad(y2, ((0, pad), (0, 0))) if pad else y2
     steps = (n + pad) // chunk
     x32 = x.astype(jnp.float32)
-    x2 = jnp.sum(x32 * x32, axis=1, keepdims=True)
     rows = jnp.asarray(row0, jnp.int32) + jnp.arange(m, dtype=jnp.int32)[
         :, None
     ]
@@ -264,10 +294,11 @@ def knn_topk_ref(
         yc = jax.lax.dynamic_slice_in_dim(
             y_p, c * chunk, chunk, 0
         ).astype(jnp.float32)
-        y2 = jnp.sum(yc * yc, axis=1, keepdims=True)
+        y2 = jax.lax.dynamic_slice_in_dim(y2_p, c * chunk, chunk, 0)
         xy = jax.lax.dot_general(
             x32, yc,
             dimension_numbers=(((1,), (1,)), ((), ())),
+            precision=DOT_PRECISION,
             preferred_element_type=jnp.float32,
         )
         d = jnp.maximum(x2 + y2.T - 2.0 * xy, 0.0)
@@ -277,10 +308,16 @@ def knn_topk_ref(
         dead = (rows == cols) | (cols >= hi)
         d = jnp.where(dead, jnp.inf, d)
         ci = jnp.where(dead, -1, jnp.broadcast_to(cols, d.shape))
-        cat_d = jnp.concatenate([bd, d], axis=1)
-        cat_i = jnp.concatenate([bi, ci], axis=1)
-        neg, pos = jax.lax.top_k(-cat_d, k)
-        return -neg, jnp.take_along_axis(cat_i, pos, axis=1)
+        # the chunk's columns ascend, so lax.top_k's first-wins tie rule
+        # already ranks them by (distance, index); only its best k can
+        # reach the merged list
+        neg, pos = jax.lax.top_k(-d, min(k, chunk))
+        return topk_by_index(
+            jnp.concatenate([bd, -neg], axis=1),
+            jnp.concatenate([bi, jnp.take_along_axis(ci, pos, axis=1)],
+                            axis=1),
+            k,
+        )
 
     return jax.lax.fori_loop(
         0, steps, body,

@@ -298,9 +298,7 @@ def lower_isomap_cell(stage: str, *, multi_pod: bool):
         elif stage == "center":
             g_sds = jax.ShapeDtypeStruct((n, n), jnp.float32)
             g_shard = NamedSharding(mesh, P(data_axis, "model"))
-            from repro import compat
-
-            smfn = compat.shard_map(
+            smfn = jax.shard_map(
                 lambda t: centering.double_center_local(
                     jnp.square(t), data_axis=data_axis, model_axis="model",
                     n=n,

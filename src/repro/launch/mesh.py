@@ -4,13 +4,13 @@ A function (not a module-level constant) so importing this module never
 touches jax device state - the dry-run sets XLA_FLAGS before any jax
 initialization and only then calls make_production_mesh().
 
-Mesh construction is routed through :mod:`repro.compat` so the
-``AxisType.Auto`` annotation is applied on jax releases that support it
-and silently dropped on those that predate it.
+Every mesh carries ``AxisType.Auto`` axes: sharding inside them is left
+to the compiler (GSPMD) except where a shard_map body takes over.
 """
 from __future__ import annotations
 
-from repro import compat
+import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -19,9 +19,11 @@ def make_production_mesh(*, multi_pod: bool = False):
     (ICI-local within a pod)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
-def make_mesh(shape, axes):
+def make_mesh(shape, axes) -> jax.sharding.Mesh:
     """Arbitrary mesh for tests / laptop runs."""
-    return compat.make_mesh(shape, axes)
+    return jax.make_mesh(
+        tuple(shape), tuple(axes), axis_types=(AxisType.Auto,) * len(axes)
+    )

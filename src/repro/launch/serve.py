@@ -23,6 +23,7 @@ import numpy as np
 
 from repro import configs
 from repro.launch import mesh as mesh_lib
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import build_model
 from repro.sharding import LogicalRules, materialize, spec_shardings
 
@@ -477,6 +478,7 @@ def build_parser() -> argparse.ArgumentParser:
 def main():
     ap = build_parser()
     args = ap.parse_args()
+    enable_compile_cache()
     if args.manifold:
         mesh_shape = None
         if args.mesh:

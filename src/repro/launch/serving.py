@@ -344,15 +344,18 @@ class BatchedMapperService:
             fut.set_result(report)
 
     def _flush(self, reqs: list[_Request]):
-        xs = np.concatenate([r.x for r in reqs], axis=0)
-        n = xs.shape[0]
         try:
+            xs = np.concatenate([r.x for r in reqs], axis=0)
+            n = xs.shape[0]
             if self.pad_batches and 0 < n < self.max_batch:
                 pad = np.zeros((self.max_batch - n, xs.shape[1]), xs.dtype)
                 y = np.asarray(self.mapper(np.concatenate([xs, pad])))[:n]
             else:
                 y = np.asarray(self.mapper(xs))
-        except Exception as e:  # pragma: no cover - surfaced via futures
+        except Exception as e:
+            # a failed flush fails every request in it, through the future
+            # each caller reads: nothing escapes to the scheduler thread or
+            # to a worker-pool future no one reads
             for r in reqs:
                 r.future.set_exception(e)
             return

@@ -14,8 +14,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from repro import compat
-
 Tree = Any
 
 
@@ -34,7 +32,7 @@ def error_feedback_allreduce(grads: Tree, residual: Tree, axis: str):
     (error feedback), which is what keeps convergence unharmed.
     Returns (reduced_grads, new_residual).
     """
-    size = compat.axis_size(axis)
+    size = jax.lax.axis_size(axis)
 
     def one(g, r):
         gf = g.astype(jnp.float32) + r

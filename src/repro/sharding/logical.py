@@ -222,12 +222,10 @@ def folded_axis_index(axis):
     shard_map bodies."""
     import jax
 
-    from repro import compat
-
     if isinstance(axis, (tuple, list)):
         idx = jax.lax.axis_index(axis[0])
         for a in axis[1:]:
-            idx = idx * compat.axis_size(a) + jax.lax.axis_index(a)
+            idx = idx * jax.lax.axis_size(a) + jax.lax.axis_index(a)
         return idx
     return jax.lax.axis_index(axis)
 

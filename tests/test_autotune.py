@@ -11,7 +11,7 @@ def test_best_config_is_valid_and_beats_default():
         for m, n, k in ((256, 2048, 256), (128, 512, 128), (512, 512, 512)):
             cfg, cost = autotune.best_config(op, m, n, k)
             assert autotune.divides(cfg, m, n, k), (op, m, n, k, cfg)
-            assert cost.vmem_bytes <= autotune.VMEM_BUDGET
+            assert cost.vmem_bytes <= autotune.vmem_budget()
             dflt = autotune.default_config(m, n, k)
             dcost = autotune.modeled_cost(op, m, n, k, dflt)
             assert cost.time_s <= dcost.time_s * (1.0 + 1e-9)
@@ -186,7 +186,7 @@ def test_minplus_border_is_a_seeded_op():
 def test_knn_best_config_beats_default():
     for m, n, d, k in ((256, 2048, 3, 10), (64, 500, 8, 7), (8, 8, 2, 3)):
         cfg, cost = autotune.best_knn_config(m, n, d, k)
-        assert cost.vmem_bytes <= autotune.VMEM_BUDGET
+        assert cost.vmem_bytes <= autotune.vmem_budget()
         dflt = autotune.KnnConfig(
             min(autotune.KNN_DEFAULT.bm, m), min(autotune.KNN_DEFAULT.bn, n)
         )
@@ -217,7 +217,63 @@ def test_knn_env_autotune_disable(monkeypatch):
 
 
 def test_pairwise_tiles_divide():
-    for m, n, d in ((100, 52, 3), (97, 31, 7), (512, 512, 784), (1, 1, 1)):
+    """Auto tiles are a whole dim or a multiple of the chip's (8, 128)
+    register tiling, and divide the operands ops.pairwise_sq_dists pads
+    to tile multiples (zero rows stripped, zero features exact)."""
+    for m, n, d in ((100, 52, 3), (97, 31, 7), (512, 512, 784), (1, 1, 1),
+                    (64, 1000, 3)):
         t = autotune.pairwise_tiles(m, n, d)
-        assert m % t["bm"] == 0 and n % t["bn"] == 0 and d % t["bd"] == 0
+        for key, dim, align in (("bm", m, 8), ("bn", n, 128), ("bd", d, 128)):
+            assert t[key] == dim or t[key] % align == 0, (m, n, d, t)
         assert max(t.values()) <= 512
+    rng = np.random.default_rng(0)
+    x = rng.normal(size=(100, 700)).astype(np.float32)
+    y = rng.normal(size=(600, 700)).astype(np.float32)
+    got = ops.pairwise_sq_dists(x, y, mode="pallas")
+    assert got.shape == (100, 600)
+    np.testing.assert_allclose(
+        got, ref.pairwise_sq_dists_ref(x, y), rtol=1e-4, atol=1e-3
+    )
+
+
+def test_machine_constants_keyed_by_device_kind(monkeypatch):
+    """Constants come from the entry of the chip in use; a TPU without
+    an entry raises instead of being tuned with v5e numbers, and off-TPU
+    the v5e entry picks tiles that chip accepts."""
+    import types
+
+    import jax
+
+    def fake(platform, kind):
+        dev = types.SimpleNamespace(platform=platform, device_kind=kind)
+        monkeypatch.setattr(jax, "devices", lambda *a, **k: [dev])
+        autotune.chip.cache_clear()
+
+    try:
+        fake("tpu", "TPU v5 lite")
+        assert autotune.chip() is autotune.CHIPS["TPU v5 lite"]
+        assert "Google Cloud" in autotune.chip().source
+        fake("cpu", "cpu")
+        assert autotune.chip() is autotune.CHIPS[autotune.DEFAULT_CHIP]
+        fake("tpu", "TPU v99")
+        with pytest.raises(ValueError, match="TPU v99"):
+            autotune.chip()
+        with pytest.raises(ValueError, match="no machine constants"):
+            autotune.best_config("minplus_update", 640, 640, 640)
+    finally:
+        autotune.chip.cache_clear()
+        autotune.best_config.cache_clear()
+
+
+def test_minplus_candidates_are_chip_tilings():
+    """Every min-plus candidate is a tiling the chip's compiler takes:
+    bm a multiple of 8 up to MAX_BM, bn/bk multiples of 128, or whole
+    dims."""
+    for m, n, k in ((16384, 16384, 128), (128, 16384, 128),
+                    (16384, 128, 128), (32, 16384, 16384), (96, 200, 40)):
+        for cfg in autotune.candidates(m, n, k):
+            c = autotune.clamp(cfg, m, n, k)
+            assert c.bm <= autotune.MAX_BM or c.bm == m
+            assert c.bm % 8 == 0 or c.bm == m
+            assert c.bn % 128 == 0 or c.bn == n
+            assert c.bk % 128 == 0 or c.bk == k

@@ -69,9 +69,8 @@ print("OK distributed-e2e", err)
 from jax.sharding import PartitionSpec as P2
 def body(g, r):
     return error_feedback_allreduce({"g": g}, {"g": r}, "data")
-from repro import compat
-fn = compat.shard_map(body, mesh=mesh, in_specs=(P("data"), P("data")),
-                      out_specs=(P(None), P("data")), check_vma=False)
+fn = jax.shard_map(body, mesh=mesh, in_specs=(P("data"), P("data")),
+                   out_specs=(P(None), P("data")), check_vma=False)
 rng = np.random.default_rng(0)
 g = jnp.asarray(rng.normal(size=(8, 64)), jnp.float32)
 r = jnp.zeros((8, 64), jnp.float32)

@@ -277,11 +277,11 @@ def test_pairwise_tile_override_validation(rng):
 
 
 def _brute_knn(x, y, k, row0=0, col0=0, n_valid=None):
-    """Brute-force (distance, column)-ranked top-k with first-wins ties:
-    the independent witness both the kernel and the oracle must match.
-    Distances use the kernel's own f32 x2 + y2 - 2<x,y> form so that
-    near-ties order identically (the first-wins rule is only meaningful
-    on bitwise-equal values)."""
+    """Brute-force (distance, column)-ranked top-k, ties to the smaller
+    column: the independent witness both the kernel and the oracle must
+    match.  Distances use the kernel's own f32 x2 + y2 - 2<x,y> form so
+    that near-ties order identically (the tie rule is only meaningful on
+    bitwise-equal values)."""
     x = x.astype(np.float32)
     y = y.astype(np.float32)
     x2 = (x * x).sum(1, keepdims=True)
@@ -336,9 +336,9 @@ def test_knn_topk_matches_oracle_and_brute(m, n, d, k, bm, bn, rng):
 
 
 def test_knn_topk_tie_breaking_on_duplicates(rng):
-    """Duplicate points force exact distance ties; the first-wins rule
-    (lower column index) must hold bit for bit on every tiling and in
-    the oracle."""
+    """Duplicate points force exact distance ties; the tie rule (lower
+    column index) must hold bit for bit on every tiling and in the
+    oracle."""
     base = rng.normal(size=(16, 4)).astype(np.float32)
     y = np.concatenate([base, base, base])  # every row exists 3x
     x = base.copy()
@@ -355,6 +355,26 @@ def test_knn_topk_tie_breaking_on_duplicates(rng):
         assert np.array_equal(od, outs[0][0])
         assert np.array_equal(oi, outs[0][1])
     assert np.array_equal(outs[0][1], want_i)
+
+
+@pytest.mark.parametrize("mode", ["pallas", "ref"])
+def test_knn_topk_ties_independent_of_chaining_order(mode, rng):
+    """Ties go to the smaller column however the columns arrive: folding
+    the column shards in descending order through the seed lists (as a
+    ring step on a later shard does) gives the lists of one call."""
+    base = rng.normal(size=(16, 4)).astype(np.float32)
+    y = np.concatenate([base, base, base])  # every row exists 3x
+    x = base.copy()
+    k = 6
+    sd, si = _empty_seed(16, k)
+    want_d, want_i = ops.knn_topk(x, y, sd, si, mode=mode, bm=8, bn=16)
+    d, i = sd, si
+    for c in (32, 16, 0):
+        d, i = ops.knn_topk(x, y[c:c + 16], d, i, col0=c, mode=mode,
+                            bm=8, bn=16)
+    assert np.array_equal(np.asarray(d), np.asarray(want_d))
+    assert np.array_equal(np.asarray(i), np.asarray(want_i))
+    assert np.array_equal(np.asarray(want_i), _brute_knn(x, y, k)[1])
 
 
 def test_knn_topk_k_exceeds_candidates(rng):

@@ -426,6 +426,29 @@ def test_absorb_errors_surface_via_future():
             fut.result(timeout=30)
 
 
+@pytest.mark.parametrize("depth", [1, 2])
+def test_flush_errors_surface_via_future(depth):
+    """A failed flush fails each request in it through its own future, on
+    the scheduler thread and on the worker pool alike, and the service
+    keeps serving: a mapper error, and a batch that cannot even be
+    assembled (feature widths differ), both reach f.result()."""
+    def mapper(x):
+        if x.shape[1] == 5:
+            raise RuntimeError("mapper failed")
+        return np.zeros((x.shape[0], 2), np.float32)
+
+    with BatchedMapperService(
+        mapper, max_batch=4, max_latency_ms=20.0, pipeline_depth=depth
+    ) as s:
+        with pytest.raises(RuntimeError, match="mapper failed"):
+            s.submit(np.zeros((2, 5), np.float32)).result(timeout=30)
+        mixed = [s.submit(np.zeros((1, w), np.float32)) for w in (3, 4)]
+        for f in mixed:
+            with pytest.raises(ValueError):
+                f.result(timeout=30)
+        assert s.map(np.zeros((2, 3), np.float32)).shape == (2, 2)
+
+
 # --------------------------------------------------- pipelined dispatch --
 
 

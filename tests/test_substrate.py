@@ -149,9 +149,11 @@ def test_pipeline_deterministic_and_seekable():
 def _abstract_mesh(shape, axes):
     """Rules only need shape/axis_names; AbstractMesh avoids requiring
     real devices in the 1-CPU test process."""
-    from repro.compat import abstract_mesh
+    from jax.sharding import AbstractMesh, AxisType
 
-    return abstract_mesh(shape, axes)
+    return AbstractMesh(
+        tuple(shape), tuple(axes), axis_types=(AxisType.Auto,) * len(axes)
+    )
 
 
 def test_logical_rules_divisibility_fallback():
@@ -208,3 +210,30 @@ def test_train_restart_bitwise(tmp_path):
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), rtol=1e-6, atol=1e-6
         )
+
+
+def test_compile_cache_placement(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR, when set, is left to JAX; otherwise the
+    cache goes to one fixed directory inside the checkout, which git
+    ignores."""
+    import pathlib
+
+    import jax
+
+    from repro.launch import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv(compile_cache.ENV_CACHE_DIR, "/elsewhere/cache")
+        assert compile_cache.enable_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv(compile_cache.ENV_CACHE_DIR)
+        got = compile_cache.enable_compile_cache()
+        assert got == compile_cache.DEFAULT_DIR
+        assert jax.config.jax_compilation_cache_dir == got
+        root = pathlib.Path(__file__).resolve().parents[1]
+        assert pathlib.Path(got) == root / ".jax_cache"
+        ignored = (root / ".gitignore").read_text().split()
+        assert ".jax_cache/" in ignored
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
