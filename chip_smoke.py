@@ -9,18 +9,21 @@ calls (:func:`repro.launch.serve.serve_manifold`, ``ManifoldPipeline``):
 (a) device check: without a TPU the script names the device it found and
     exits non-zero; it never carries on on the CPU.
 (b) dense: fit n_base = 16384 on the Euler-isometric Swiss roll, absorb
-    32 arrivals and serve 1024 reads; the lowered APSP segment must hold
-    ``tpu_custom_call`` (the Pallas kernels ran, not the references).
+    two flushes of 32 arrivals (the second grows a base of 16416 points,
+    no multiple of 128) and serve 1024 reads; the lowered APSP segment
+    must hold ``tpu_custom_call`` (the Pallas kernels ran, not the
+    references).
 (c) reference: at n = 4096 the fit with ``kernel_mode="ref"`` and the
     default fit agree — kNN lists and geodesics bit for bit, the
     oracles' contract.
 (d) sparse: fit n_base = 65536 (a size the dense budget refuses) with
-    default landmarks, absorb and serve; the frontier solver's rows at
-    that size agree with a host Dijkstra on the same graph.
+    default landmarks, absorb two flushes and serve; the frontier
+    solver's rows at that size agree with a host Dijkstra on the same
+    graph.
 
 ``--chips 4`` runs only the mesh phase: ``serve_manifold`` on a (2, 2)
-mesh at n_base = 16384, and the mesh fit's kNN lists, geodesics and
-embedding against the one-chip fit on device 0.
+mesh at n_base = 16384 with two absorb flushes, and the mesh fit's kNN
+lists, geodesics and embedding against the one-chip fit on device 0.
 
 Each phase prints its numbers on its own line; every time is cold-cache
 set-up (compilation included), not a steady-state speed.  The last line
@@ -42,6 +45,9 @@ PROCRUSTES_LIMIT = 5e-3
 GEODESIC_RTOL = 1e-6
 #: mesh vs one-chip embedding, as a share of the embedding's range
 EMBEDDING_RTOL = 1e-3
+#: every serving phase absorbs 64 arrivals in two flushes of 32: the
+#: second grows a base the first left at no multiple of 128
+ABSORB = dict(absorb=64, absorb_flushes=2)
 
 
 class SmokeFailure(RuntimeError):
@@ -63,6 +69,13 @@ def peak_bytes(dev) -> int | str:
     return stats.get("peak_bytes_in_use", "not reported")
 
 
+def check_absorbs(phase: str, out: dict) -> None:
+    check(out["absorbed"] > 0, f"{phase} absorb folded nothing in")
+    check(out["serving_version"] >= ABSORB["absorb_flushes"],
+          f"{phase}: {out['serving_version']} absorb flushes published, "
+          f"{ABSORB['absorb_flushes']} expected")
+
+
 def serve_numbers(out: dict) -> dict:
     return {
         "fit_s": out["fit_s"],
@@ -70,6 +83,7 @@ def serve_numbers(out: dict) -> dict:
         "p50_ms": out["latency_p50_ms"],
         "p99_ms": out["latency_p99_ms"],
         "absorbed": out["absorbed"],
+        "serving_version": out["serving_version"],
         "procrustes_error": out["procrustes_error"],
         "residual_variance": out["residual_variance"],
     }
@@ -84,13 +98,13 @@ def phase_dense(dev, *, n_base=16384, n_stream=1024, block=128):
 
     out = serve_manifold(
         regime="dense", n_base=n_base, k=10, d=2, block=block,
-        n_stream=n_stream, stream_batch=64, absorb=32,
+        n_stream=n_stream, stream_batch=64, **ABSORB,
     )
     log("dense", **serve_numbers(out), peak_bytes_in_use=peak_bytes(dev))
     check(out["regime"] == "dense", f"dense phase ran {out['regime']}")
     check(out["procrustes_error"] < PROCRUSTES_LIMIT,
           f"dense procrustes {out['procrustes_error']} >= {PROCRUSTES_LIMIT}")
-    check(out["absorbed"] > 0, "dense absorb folded nothing in")
+    check_absorbs("dense", out)
     g = jax.ShapeDtypeStruct((n_base, n_base), jnp.float32)
     lowered = apsp.apsp_blocked_segment.lower(
         g, jnp.int32(0), jnp.int32(1), block=block, mode="auto"
@@ -164,7 +178,7 @@ def phase_sparse(dev, *, n_base=65536, n_stream=256, sources=16):
 
     out = serve_manifold(
         regime="sparse", n_base=n_base, k=10, d=2, n_stream=n_stream,
-        stream_batch=64, absorb=32,
+        stream_batch=64, **ABSORB,
     )
     log("sparse", **serve_numbers(out),
         dense_budget_ok=sparse.dense_budget_ok(n_base),
@@ -173,7 +187,7 @@ def phase_sparse(dev, *, n_base=65536, n_stream=256, sources=16):
     check(out["procrustes_error"] < PROCRUSTES_LIMIT,
           f"sparse procrustes {out['procrustes_error']} >= "
           f"{PROCRUSTES_LIMIT}")
-    check(out["absorbed"] > 0, "sparse absorb folded nothing in")
+    check_absorbs("sparse", out)
 
     # the frontier solver at this size against a host Dijkstra on the
     # same padded-CSR graph (tests/test_sparse.py's 1e-5 tolerance)
@@ -216,12 +230,12 @@ def phase_mesh(devs, *, n_base=16384, n_stream=256, block=128):
 
     out = serve_manifold(
         regime="dense", n_base=n_base, k=10, d=2, block=block,
-        n_stream=n_stream, stream_batch=64, absorb=32, mesh_shape=(2, 2),
+        n_stream=n_stream, stream_batch=64, mesh_shape=(2, 2), **ABSORB,
     )
     log("mesh", **serve_numbers(out))
     check(out["procrustes_error"] < PROCRUSTES_LIMIT,
           f"mesh procrustes {out['procrustes_error']} >= {PROCRUSTES_LIMIT}")
-    check(out["absorbed"] > 0, "mesh absorb folded nothing in")
+    check_absorbs("mesh", out)
 
     # the same fit on the mesh and on device 0 alone (features padded to
     # the width serve_manifold uses on a mesh; zero columns change no

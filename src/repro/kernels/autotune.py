@@ -6,28 +6,34 @@ The fused kernels (:func:`repro.kernels.ops.minplus_update`,
 :func:`~repro.kernels.ops.minplus_border`) take static tile sizes
 ``(bm, bn, bk, unroll)``.  The historical defaults (256, 256, 256, 8) are
 a fine center of the space but are not optimal for every problem shape:
-small panels leave the grid degenerate, skinny contractions want a larger
-``unroll``, and big tiles can blow the VMEM working set.
+small panels leave the grid degenerate and big tiles can blow the VMEM
+working set.
 
 This module picks the tiles **at trace time** from an analytic roofline
-model - the same machine model :mod:`repro.launch.dryrun` and
-:mod:`repro.launch.analytics` score whole pipeline stages with (the
-constants below are their single source of truth).  Min-plus runs on the
-VPU (the MXU systolic array only does *,+), so a candidate's cost is::
+model - the same machine model :mod:`repro.launch.analytics` scores whole
+pipeline stages with (:data:`CHIPS` is its single source of truth).
+Min-plus runs on the VPU (the MXU systolic array only does *,+), so a
+candidate's cost is::
 
     time = max(compute, memory)
-    compute = 2*m*n*k / (VPU_OPS * lane_fill * sublane_fill * unroll_eff)
-    memory  = HBM bytes(tiling) / HBM_BW
+    compute = 2*m*n*k / (vpu_ops * lane_fill * sublane_fill)
+    memory  = HBM bytes(tiling) / hbm_bw
 
 where ``lane_fill``/``sublane_fill`` penalize tiles under the (8, 128)
-VPU register shape, ``unroll_eff = 2u/(2u+1)`` charges the running-min
-pass each rank-``unroll`` step performs on top of the broadcast-add/min,
-and HBM bytes count the seed read + output write + the per-grid-pass
-contraction re-reads (``a`` is re-read n/bn times, ``b`` m/bm times).
-Candidates whose double-buffered VMEM working set exceeds the budget are
-discarded.
+VPU register shape and HBM bytes count the seed read + output write + the
+per-grid-pass contraction re-reads (``a`` is re-read n/bn times, ``b``
+m/bm times).  Candidates whose double-buffered VMEM working set exceeds
+the budget are discarded.  ``unroll`` is not swept: the kernel does one
+add and one min per contraction term whatever its grouping, so the model
+has nothing to rank it by, and every candidate carries the default
+grouping (:func:`grouping`).
 
-The sweep is pure arithmetic over a few hundred candidates, cached
+A dim longer than :data:`WHOLE_DIM_MAX` is tiled only in multiples of the
+chip's (8, 128) register tiling; :func:`padded_shape` is the problem
+ops.py runs after padding such a dim with +inf, and the sweep tiles that
+problem.
+
+The sweep is pure arithmetic over some tens of candidates, cached
 in-process per ``(op, m, n, k, itemsize)`` - so the cost is paid once per
 problem shape per process, at trace time, exactly like the kernels' own
 jit cache.
@@ -112,12 +118,6 @@ def chip() -> Chip:
     return CHIPS[kind]
 
 
-# The modeled target of the stage-level rooflines: repro.launch
-# .analytics and repro.launch.dryrun import these, so the kernel tuner
-# and the pipeline cost model agree about the hardware they model.
-PEAK_FLOPS, VPU_OPS, HBM_BW, ICI_BW = CHIPS[DEFAULT_CHIP][:4]
-
-
 def vmem_budget() -> int:
     """VMEM a kernel's pipelined working set (double-buffered streamed
     inputs) may take on the chip in use (:func:`chip`): half the scoped
@@ -196,11 +196,10 @@ def modeled_cost(
 
     hw = chip()
     # compute: 2 VPU ops (add + min) per (i, j, k) triple, derated by
-    # register fill and the extra running-min pass per rank-unroll step
+    # register fill
     lane_fill = min(bn, 128) / 128.0
     sublane_fill = min(bm, 8) / 8.0
-    unroll_eff = (2.0 * unroll) / (2.0 * unroll + 1.0)
-    eff_ops = hw.vpu_ops * lane_fill * sublane_fill * unroll_eff
+    eff_ops = hw.vpu_ops * lane_fill * sublane_fill
     compute_s = (2.0 * m * n * k) / eff_ops
 
     # memory: contraction operands are re-fetched once per orthogonal
@@ -214,8 +213,8 @@ def modeled_cost(
     hbm_s = hbm_bytes / (hbm_bw if hbm_bw else hw.hbm_bw)
 
     # VMEM working set: a + b tiles (double-buffered while streaming),
-    # accumulator + output tile (+ seed tile view), and the transient
-    # (unroll, bm, bn) broadcast intermediate
+    # accumulator + output tile (+ seed tile view), and the (bm, bn)
+    # broadcast terms of one unroll group
     vmem = itemsize * (
         2 * (bm * bk + bk * bn)
         + (3 if seeded else 2) * bm * bn
@@ -230,16 +229,38 @@ def modeled_cost(
     )
 
 
+#: longest dim a block may span whole; a longer dim is tiled in multiples
+#: of the chip's (8, 128) register tiling, so one block never holds more
+#: than this many rows or lanes of an unaligned dim
+WHOLE_DIM_MAX = 256
+
+
+def padded_dim(dim: int) -> int:
+    """``dim`` itself up to :data:`WHOLE_DIM_MAX`, else rounded up to a
+    multiple of it.  A function of the value alone, so the dims a kernel
+    binds to one buffer (a panel's rows and its contraction) pad alike,
+    and the static default's 256-tiles divide every padded dim."""
+    if dim <= WHOLE_DIM_MAX:
+        return dim
+    return -(-dim // WHOLE_DIM_MAX) * WHOLE_DIM_MAX
+
+
+def padded_shape(m: int, n: int, k: int) -> tuple[int, int, int]:
+    """The (m, n, k) problem ops.py runs a min-plus kernel at: each dim
+    :func:`padded_dim`-padded with +inf, which never wins a min."""
+    return padded_dim(m), padded_dim(n), padded_dim(k)
+
+
 def _tile_sizes(dim: int, *, cap: int = 512, align: int = 8) -> list[int]:
-    """Power-of-two tile sizes dividing ``dim`` that are multiples of
-    ``align`` (plus ``dim`` itself when it fits under ``cap`` or nothing
-    else divides it, so odd shapes still get a config).  The chip takes
-    a block dim that is a multiple of its (8, 128) register tiling or the
-    whole array dim: ``align`` is 8 for a sublane dim, 128 for a lane
-    dim."""
+    """Power-of-two tile sizes up to ``cap`` dividing ``dim`` that are
+    multiples of ``align``, plus ``dim`` itself when it is at most
+    :data:`WHOLE_DIM_MAX`.  The chip takes a block dim that is a multiple
+    of its (8, 128) register tiling or the whole array dim: ``align`` is
+    8 for a sublane dim, 128 for a lane dim.  Every :func:`padded_dim`
+    gets at least one size."""
     sizes = [t for t in (8, 16, 32, 64, 128, 256, 512)
              if t % align == 0 and t <= min(dim, cap) and dim % t == 0]
-    if not sizes or dim <= cap:
+    if dim <= WHOLE_DIM_MAX:
         sizes.append(dim)
     return sorted(set(sizes))
 
@@ -250,22 +271,30 @@ def _tile_sizes(dim: int, *, cap: int = 512, align: int = 8) -> list[int]:
 MAX_BM = 256
 
 
+def grouping(bk: int) -> int:
+    """The ``unroll`` every candidate carries: the default grouping,
+    halved until it divides ``bk``."""
+    u = min(DEFAULT.unroll, bk)
+    while bk % u:
+        u //= 2
+    return u
+
+
 def candidates(m: int, n: int, k: int) -> Iterator[TileConfig]:
-    """Enumerate valid tile configs for an (m, n, k) problem: power-of-two
-    tiles dividing each dim that the chip's compiler accepts (``bn`` and
-    ``bk`` are lane dims of some block), unrolls dividing bk.  The
-    (clamped) static default is included when it is such a tiling."""
+    """Enumerate tile configs for the :func:`padded_shape` of an
+    (m, n, k) problem: power-of-two tiles dividing each padded dim that
+    the chip's compiler accepts (``bn`` and ``bk`` are lane dims of some
+    block), each with the default :func:`grouping`.  The (clamped)
+    static default is included when it is such a tiling."""
+    m, n, k = padded_shape(m, n, k)
     seen = set()
     for bm in _tile_sizes(m, cap=MAX_BM):
         for bn in _tile_sizes(n, align=128):
             for bk in _tile_sizes(k, align=128):
-                for unroll in (1, 2, 4, 8, 16):
-                    if unroll > bk or bk % unroll:
-                        continue
-                    cfg = TileConfig(bm, bn, bk, unroll)
-                    if cfg not in seen:
-                        seen.add(cfg)
-                        yield cfg
+                cfg = TileConfig(bm, bn, bk, grouping(bk))
+                if cfg not in seen:
+                    seen.add(cfg)
+                    yield cfg
     dflt = clamp(DEFAULT, m, n, k)
     if dflt not in seen and divides(dflt, m, n, k) and _chip_legal(dflt, m, n, k):
         yield dflt
@@ -287,10 +316,12 @@ def best_config(
     hbm_bw: float | None = None, launch_s: float = 0.0,
 ) -> tuple[TileConfig, Cost]:
     """Sweep :func:`candidates` under :func:`modeled_cost` and return the
-    winner with its cost.  Cached in-process per (op, m, n, k, itemsize);
-    by construction the winner's modeled time never exceeds the static
+    winner with its cost, both for the :func:`padded_shape` of the
+    problem.  Cached in-process per (op, m, n, k, itemsize); by
+    construction the winner's modeled time never exceeds the static
     default's (the default is part of the sweep).  ``hbm_bw``/
     ``launch_s`` rank under measured-corrected machine constants."""
+    m, n, k = padded_shape(m, n, k)
     best = None
     fallback = None  # smallest-working-set candidate, if none fit budget
     budget = vmem_budget()
@@ -308,9 +339,8 @@ def best_config(
         if best is None or key < best[0]:
             best = (key, cfg, cost)
     if best is None:
-        # degenerate shape (e.g. no power-of-two divisor, whole-dim tiles
-        # only): every candidate busts the budget - return the smallest
-        # working set rather than a non-divisible config
+        # every candidate busts the budget: return the smallest working
+        # set rather than a non-divisible config
         best = fallback
     return best[1], best[2]
 

@@ -19,11 +19,17 @@ the Phase-2 panel kernels, and the border-expansion kernel):
   1. Explicit ``bm``/``bn``/``bk``/``unroll`` kwargs win and are
      validated *up front* - a non-divisible tile raises a ``ValueError``
      naming the offending dimension instead of surfacing as a raw
-     assertion from inside the Pallas trace.
-  2. Otherwise the fused kernels consult the trace-time roofline
-     autotuner (:mod:`repro.kernels.autotune`: in-process cache, env
-     overrides ``REPRO_MINPLUS_TILES`` / ``REPRO_MINPLUS_AUTOTUNE=0``).
-  3. Plain ``minplus`` falls back to the kernels' static defaults.
+     assertion from inside the Pallas trace.  The operands run as given.
+  2. Otherwise every dim longer than ``autotune.WHOLE_DIM_MAX`` is
+     padded with +inf to :func:`repro.kernels.autotune.padded_shape`
+     (a padded contraction term is +inf and never wins a min; padded
+     output rows/columns are stripped), so any shape runs on tiles the
+     chip accepts.  The fused kernels then consult the trace-time
+     roofline autotuner for the padded problem
+     (:mod:`repro.kernels.autotune`: in-process cache, env overrides
+     ``REPRO_MINPLUS_TILES`` / ``REPRO_MINPLUS_AUTOTUNE=0``).
+  3. Plain ``minplus`` takes the kernels' static defaults, which divide
+     every padded dim.
 
 This module also hosts the roofline decision for the APSP Phase-2
 ``split_panels`` variant (:func:`auto_split_panels`): whether each mesh
@@ -139,36 +145,55 @@ def _validate_tiles(
         )
 
 
-def _tiles(op: str, m: int, n: int, k: int, tile_kw: dict) -> dict:
-    """Resolve the tile kwargs for one fused-kernel launch: validate any
-    explicit override, otherwise consult the autotuner (env pins, the
-    measured-calibration store, then the analytic sweep)."""
+def _tiles(
+    op: str, m: int, n: int, k: int, tile_kw: dict, *, tuned: bool = True
+) -> tuple[dict, tuple[int, int, int]]:
+    """Resolve the tile kwargs for one min-plus launch -> (tile kwargs,
+    the (m, n, k) the kernel runs at).  An explicit override is validated
+    against the operands as given, which then run unpadded; otherwise
+    the problem is :func:`autotune.padded_shape` and, when ``tuned``,
+    the autotuner tiles it (env pins, the measured-calibration store,
+    then the analytic sweep)."""
     if tile_kw:
         _validate_tiles(op, m, n, k, tile_kw)
-        return tile_kw
+        return tile_kw, (m, n, k)
+    m, n, k = autotune.padded_shape(m, n, k)
+    if not tuned:
+        return {}, (m, n, k)
     resolved, source = autotune.resolve_tiles(op, m, n, k)
     if resolved:
         # analytic configs divide by construction; this guards the
         # REPRO_MINPLUS_TILES env pin and calibration-store entries with
         # the same clear error, attributed to their source
         _validate_tiles(op, m, n, k, resolved, source=source)
-    return resolved
+    return resolved, (m, n, k)
+
+
+def _pad_inf(x, rows: int, cols: int):
+    """``x`` padded with +inf to (rows, cols)."""
+    pr, pc = rows - x.shape[0], cols - x.shape[1]
+    if not (pr or pc):
+        return x
+    return jnp.pad(x, ((0, pr), (0, pc)), constant_values=jnp.inf)
 
 
 def minplus(a, b, *, mode: str = "auto", **tile_kw):
     """Tropical (min-plus) matrix product C[i,j] = min_k A[i,k] + B[k,j].
 
     a (m, k), b (k, n) -> (m, n).  Tile kwargs (bm/bn/bk/unroll) are
-    validated up front; without them the kernel's static defaults apply.
+    validated up front; without them the operands are padded and the
+    kernel's static defaults apply.
     """
     m, k = a.shape
     n = b.shape[1]
-    if tile_kw:
-        _validate_tiles("minplus", m, n, k, tile_kw)
+    tile_kw, (mp, np_, kp) = _tiles("minplus", m, n, k, tile_kw,
+                                    tuned=False)
     use_pallas, interpret = _resolve(mode)
-    if use_pallas:
-        return _mp_pallas(a, b, interpret=interpret, **tile_kw)
-    return _ref.minplus_ref(a, b)
+    if not use_pallas:
+        return _ref.minplus_ref(a, b)
+    out = _mp_pallas(_pad_inf(a, mp, kp), _pad_inf(b, kp, np_),
+                     interpret=interpret, **tile_kw)
+    return out[:m, :n]
 
 
 def minplus_update(g, c, r, *, mode: str = "auto", **tile_kw):
@@ -182,11 +207,15 @@ def minplus_update(g, c, r, *, mode: str = "auto", **tile_kw):
     """
     m, n = g.shape
     k = c.shape[1]
-    tile_kw = _tiles("minplus_update", m, n, k, tile_kw)
+    tile_kw, (mp, np_, kp) = _tiles("minplus_update", m, n, k, tile_kw)
     use_pallas, interpret = _resolve(mode)
-    if use_pallas:
-        return _mpu_pallas(g, c, r, interpret=interpret, **tile_kw)
-    return _ref.minplus_update_ref(g, c, r)
+    if not use_pallas:
+        return _ref.minplus_update_ref(g, c, r)
+    out = _mpu_pallas(
+        _pad_inf(g, mp, np_), _pad_inf(c, mp, kp), _pad_inf(r, kp, np_),
+        interpret=interpret, **tile_kw,
+    )
+    return out[:m, :n]
 
 
 def minplus_panel_row(d, r, *, mode: str = "auto", **tile_kw):
@@ -200,11 +229,13 @@ def minplus_panel_row(d, r, *, mode: str = "auto", **tile_kw):
     Tiles: explicit kwargs win (validated up front), else autotuned.
     """
     b, n = r.shape
-    tile_kw = _tiles("minplus_panel_row", b, n, b, tile_kw)
+    tile_kw, (bp, np_, _) = _tiles("minplus_panel_row", b, n, b, tile_kw)
     use_pallas, interpret = _resolve(mode)
-    if use_pallas:
-        return _mpr_pallas(d, r, interpret=interpret, **tile_kw)
-    return _ref.minplus_panel_row_ref(d, r)
+    if not use_pallas:
+        return _ref.minplus_panel_row_ref(d, r)
+    out = _mpr_pallas(_pad_inf(d, bp, bp), _pad_inf(r, bp, np_),
+                      interpret=interpret, **tile_kw)
+    return out[:b, :n]
 
 
 def minplus_panel_col(c, d, *, mode: str = "auto", **tile_kw):
@@ -218,11 +249,13 @@ def minplus_panel_col(c, d, *, mode: str = "auto", **tile_kw):
     else autotuned.
     """
     m, b = c.shape
-    tile_kw = _tiles("minplus_panel_col", m, b, b, tile_kw)
+    tile_kw, (mp, bp, _) = _tiles("minplus_panel_col", m, b, b, tile_kw)
     use_pallas, interpret = _resolve(mode)
-    if use_pallas:
-        return _mpc_pallas(c, d, interpret=interpret, **tile_kw)
-    return _ref.minplus_panel_col_ref(c, d)
+    if not use_pallas:
+        return _ref.minplus_panel_col_ref(c, d)
+    out = _mpc_pallas(_pad_inf(c, mp, bp), _pad_inf(d, bp, bp),
+                      interpret=interpret, **tile_kw)
+    return out[:m, :b]
 
 
 def minplus_border(e, a, *, mode: str = "auto", **tile_kw):
@@ -238,11 +271,13 @@ def minplus_border(e, a, *, mode: str = "auto", **tile_kw):
     else autotuned.
     """
     m, n = e.shape
-    tile_kw = _tiles("minplus_border", m, n, n, tile_kw)
+    tile_kw, (mp, np_, _) = _tiles("minplus_border", m, n, n, tile_kw)
     use_pallas, interpret = _resolve(mode)
-    if use_pallas:
-        return _mb_pallas(e, a, interpret=interpret, **tile_kw)
-    return _ref.minplus_border_ref(e, a)
+    if not use_pallas:
+        return _ref.minplus_border_ref(e, a)
+    out = _mb_pallas(_pad_inf(e, mp, np_), _pad_inf(a, np_, np_),
+                     interpret=interpret, **tile_kw)
+    return out[:m, :n]
 
 
 def frontier_relax(dist, nbr, w, hi, *, mode: str = "auto", **tile_kw):

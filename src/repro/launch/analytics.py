@@ -24,14 +24,20 @@ import jax.numpy as jnp
 from repro.models.config import ModelConfig, ShapeConfig
 from repro.models.model import build_model
 
-# TPU v5e constants (per chip); single source of truth is the kernel
-# tuner (repro.kernels.autotune) so the stage-level roofline and the
-# trace-time tile sweep can never disagree about the hardware.
+# The chip these rooflines model: a v5e pod, whatever device this
+# process runs on.  Its constants are the kernel tuner's entry for that
+# device kind (repro.kernels.autotune.CHIPS), so the stage-level roofline
+# and the trace-time tile sweep never disagree about the hardware.
 # VPU_OPS because min-plus semiring ops run on the VPU, NOT the MXU
 # (no tropical matmul in silicon).
-from repro.kernels.autotune import HBM_BW, PEAK_FLOPS, VPU_OPS  # noqa: E402
+from repro.kernels.autotune import CHIPS  # noqa: E402
 
-ICI_BW = 2 * 50e9            # B/s per mesh axis (2 links per torus axis)
+TARGET_CHIP = "TPU v5 lite"
+PEAK_FLOPS = CHIPS[TARGET_CHIP].peak_flops
+VPU_OPS = CHIPS[TARGET_CHIP].vpu_ops
+HBM_BW = CHIPS[TARGET_CHIP].hbm_bw
+# B/s per mesh axis (2 links per torus axis)
+ICI_BW = 2 * CHIPS[TARGET_CHIP].ici_bw
 
 
 @dataclasses.dataclass

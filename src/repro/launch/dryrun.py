@@ -35,11 +35,6 @@ from repro.sharding import (  # noqa: E402
 
 RESULT_DIR = os.path.join(os.path.dirname(__file__), "../../..", "experiments", "dryrun")
 
-# TPU v5e roofline constants (per chip) - shared with the kernel-tile
-# autotuner, which sweeps (bm, bn, bk, unroll) under the same machine
-# model at trace time (repro.kernels.autotune is the single source).
-from repro.kernels.autotune import HBM_BW, ICI_BW, PEAK_FLOPS  # noqa: E402
-
 # HLO line shape: `%name = TYPE all-reduce(...)` or tuple TYPE for
 # multi-operand collectives; async pairs appear as -start/-done (count the
 # start only).

@@ -116,6 +116,7 @@ def serve_manifold(
     resume: bool = False,
     checkpoint_secs: float | None = None,
     absorb: int = 0,
+    absorb_flushes: int = 1,
     mesh_shape: tuple[int, int] | None = None,
     regime: str = "auto",
     landmarks: int = 0,
@@ -146,6 +147,8 @@ def serve_manifold(
     absorb: fold the first `absorb` streamed arrivals back into the base
     geodesics through the service's write path (admission-controlled,
     runs between read flushes) before serving the rest.
+    absorb_flushes: split those arrivals into this many write calls, so
+    each later flush grows a base an earlier one already grew.
     replicas: serve reads from this many log-shipped reader replicas
     behind a consistent-hash router instead of one service; all absorbs
     still go through the single writer, whose update-log appends the
@@ -241,6 +244,13 @@ def serve_manifold(
         # a restarted server replays absorbed arrivals, not just the fit
         mapper.replay_update_log(checkpoint_dir)
     n_absorbed = 0
+    if absorb_flushes < 1:
+        raise ValueError(
+            f"absorb_flushes must be >= 1, got {absorb_flushes}"
+        )
+    absorb_parts = (
+        np.array_split(x_stream[:absorb], absorb_flushes) if absorb else []
+    )
     replica_stats: list[dict] = []
     if replicas:
         import os
@@ -275,9 +285,9 @@ def serve_manifold(
         )
         with fleet:
             t0 = time.time()
+            for part in absorb_parts:
+                n_absorbed += fleet.absorb(part).absorbed
             if absorb:
-                report = fleet.absorb(x_stream[:absorb])
-                n_absorbed = report.absorbed
                 # serve from the absorbed generation: wait for every
                 # replica to cut over before the read burst (otherwise a
                 # lagging replica answers from the pre-absorb frame -
@@ -317,12 +327,11 @@ def serve_manifold(
         with service:
             service.warmup(x_stream.shape[1])
             t0 = time.time()
-            if absorb:
-                # write path: fold early arrivals into the base geodesics;
-                # every arrival is still queried below (absorbed points are
-                # then answered from the grown base they are part of)
-                report = service.absorb(x_stream[:absorb])
-                n_absorbed = report.absorbed
+            # write path: fold early arrivals into the base geodesics;
+            # every arrival is still queried below (absorbed points are
+            # then answered from the grown base they are part of)
+            for part in absorb_parts:
+                n_absorbed += service.absorb(part).absorbed
             futures = [
                 service.submit(x_stream[lo : lo + arrival])
                 for lo in range(0, n_stream, arrival)

@@ -21,12 +21,15 @@ def test_odd_shapes_get_a_config():
     # shapes with no power-of-two divisor still resolve (whole-dim tile)
     cfg, _ = autotune.best_config("minplus_update", 20, 20, 20)
     assert autotune.divides(cfg, 20, 20, 20)
-    # ... even when the whole-dim tile busts the VMEM budget (the 700x700
-    # landmark sweep shape): the tuner must return a *valid* config, not
-    # a non-divisible clamped default
+    # ... and a long dim with no aligned divisor (the 700x700 landmark
+    # sweep shape, a base grown by an absorb) gets aligned tiles of the
+    # padded problem ops.py runs, never a whole-dim block of 700
     cfg, cost = autotune.best_config("minplus_update", 700, 700, 140)
-    assert autotune.divides(cfg, 700, 700, 140)
+    assert autotune.padded_shape(700, 700, 140) == (768, 768, 140)
+    assert autotune.divides(cfg, 768, 768, 140)
+    assert cfg.bm % 8 == 0 and cfg.bn % 128 == 0
     assert cost.vmem_bytes > 0
+    assert autotune.padded_shape(16416, 32, 16416) == (16640, 32, 16640)
 
 
 def test_seeded_ops_cost_more_memory_than_minplus():
@@ -133,9 +136,11 @@ def test_constants_are_shared_with_launch_rooflines():
     constants (single source of truth)."""
     from repro.launch import analytics
 
-    assert analytics.VPU_OPS is autotune.VPU_OPS
-    assert analytics.HBM_BW is autotune.HBM_BW
-    assert analytics.PEAK_FLOPS is autotune.PEAK_FLOPS
+    target = autotune.CHIPS[analytics.TARGET_CHIP]
+    assert analytics.VPU_OPS == target.vpu_ops
+    assert analytics.HBM_BW == target.hbm_bw
+    assert analytics.PEAK_FLOPS == target.peak_flops
+    assert not hasattr(autotune, "HBM_BW")  # no second, untargeted copy
 
 
 # --------------------------------------------- Phase-2 split-panel auto ----

@@ -158,6 +158,39 @@ def test_panel_equals_materializing_composition(rng):
         )
 
 
+@pytest.mark.parametrize(
+    "op", ["minplus", "update", "panel_row", "panel_col", "border"]
+)
+def test_unaligned_long_dims_are_padded_exactly(op, rng):
+    """A dim longer than autotune.WHOLE_DIM_MAX with no aligned divisor
+    (a base grown by an absorb) is padded with +inf to tiles the chip
+    accepts and stripped again: bit-identical to the oracle, +inf entries
+    included."""
+    def u(*shape):
+        x = rng.uniform(0, 30, shape).astype(np.float32)
+        x[x > 27.0] = np.inf
+        return x
+
+    d = _closed_diag_block(rng, 16)
+    args, oracle = {
+        "minplus": ((u(264, 16), u(16, 260)), ref.minplus_ref),
+        "update": ((u(260, 264), u(260, 16), u(16, 264)),
+                   ref.minplus_update_ref),
+        "panel_row": ((d, u(16, 300)), ref.minplus_panel_row_ref),
+        "panel_col": ((u(300, 16), d), ref.minplus_panel_col_ref),
+        "border": ((u(8, 260), _closed_diag_block(rng, 260)),
+                   ref.minplus_border_ref),
+    }[op]
+    fn = {"minplus": ops.minplus, "update": ops.minplus_update,
+          "panel_row": ops.minplus_panel_row,
+          "panel_col": ops.minplus_panel_col,
+          "border": ops.minplus_border}[op]
+    got = np.asarray(fn(*args, mode="pallas"))
+    want = np.asarray(oracle(*args))
+    assert got.shape == want.shape
+    assert np.array_equal(got, want)
+
+
 def test_tile_override_validation(rng):
     """Bad tile overrides raise a clear ValueError from ops.py, not a raw
     Pallas trace assertion - on every op that takes tiles, including the

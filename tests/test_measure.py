@@ -191,7 +191,7 @@ def test_fit_constants_recovers_bandwidth_and_launch():
 
 
 def test_fit_constants_degenerate_falls_back():
-    assert measure.fit_constants([])["hbm_bw"] == float(autotune.HBM_BW)
+    assert measure.fit_constants([])["hbm_bw"] == float(autotune.chip().hbm_bw)
     # identical times regardless of bytes: launch-dominated, analytic
     # bandwidth passes through
     flat = [[b, 0.0, 1e-3] for b in (1e6, 4e6)]
@@ -235,13 +235,13 @@ def test_scripted_timer_correction_is_monotone(monkeypatch):
 def test_corrected_constants_rerank_unmeasured_shapes():
     # constants only (no winner for this shape): resolution re-ranks the
     # analytic sweep under the fitted bandwidth/launch
-    _seed_store(constants={"hbm_bw": float(autotune.HBM_BW) / 4,
+    _seed_store(constants={"hbm_bw": float(autotune.chip().hbm_bw) / 4,
                            "launch_s": 1e-5, "n_samples": 8})
     cfg, source = autotune.resolve_tiles("minplus_update", 512, 512, 512)
     assert source == "corrected"
     want, _ = autotune.best_config(
         "minplus_update", 512, 512, 512,
-        hbm_bw=float(autotune.HBM_BW) / 4, launch_s=1e-5,
+        hbm_bw=float(autotune.chip().hbm_bw) / 4, launch_s=1e-5,
     )
     assert cfg == want._asdict()
     # the frontier and kNN families consult the same constants

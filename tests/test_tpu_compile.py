@@ -84,9 +84,30 @@ def test_floyd_warshall_compiles(b, one_chip, native):
     _assert_kernel(ops.floyd_warshall, _spec(one_chip, (b, b)))
 
 
-def test_minplus_border_compiles(one_chip, native):
+@pytest.mark.parametrize("n", [N, N + 32])
+def test_minplus_border_compiles(n, one_chip, native):
+    # N + 32: the base after one 32-point absorb, no multiple of 128
     s = one_chip
-    _assert_kernel(ops.minplus_border, _spec(s, (32, N)), _spec(s, (N, N)))
+    _assert_kernel(ops.minplus_border, _spec(s, (32, n)), _spec(s, (n, n)))
+
+
+def test_absorb_expansions_compile_on_grown_bases(one_chip, native):
+    """The second absorb of the smoke: dense expansion from an
+    (N + 32)-point base, sparse panel expansion from an unaligned
+    (N_SPARSE + 32)-point base."""
+    from repro.core import update
+
+    s = one_chip
+    n, g = N + 32, 32
+    _assert_kernel(
+        lambda a, e, f: update.expand_geodesics(a, e, f),
+        _spec(s, (n, n)), _spec(s, (g, n)), _spec(s, (g, g)),
+    )
+    n, m = N_SPARSE + 32, 1024
+    _assert_kernel(
+        lambda p, e, f: update.expand_panel(p, e, f),
+        _spec(s, (m, n)), _spec(s, (g, n)), _spec(s, (g, g)),
+    )
 
 
 def test_knn_topk_compiles(one_chip, native):

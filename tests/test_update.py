@@ -70,12 +70,14 @@ def test_border_expansion_matches_from_scratch_apsp_real_weights():
     )
 
 
-def test_border_expansion_pallas_bit_identical_to_ref(rng):
+@pytest.mark.parametrize("n", [64, 300])
+def test_border_expansion_pallas_bit_identical_to_ref(n, rng):
     """Same discipline as every other kernel: the Pallas path (interpret
-    mode here) is bit-identical to the jnp oracle composition."""
-    n, m = 64, 8
+    mode here) is bit-identical to the jnp oracle composition - also from
+    a base with no aligned tiling (n = 300, padded by the kernels)."""
+    m = 8
     g = _random_graph(np.random.default_rng(2), n + m)
-    a = apsp.apsp_blocked(jnp.asarray(g[:n, :n]), block=32, mode="ref")
+    a = apsp.apsp_blocked(jnp.asarray(g[:n, :n]), block=n // 2, mode="ref")
     e, f = jnp.asarray(g[n:, :n]), jnp.asarray(g[n:, n:])
     got = update.expand_geodesics(a, e, f, mode="pallas")
     want = update.expand_geodesics(a, e, f, mode="ref")
