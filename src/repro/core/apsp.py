@@ -48,6 +48,7 @@ from repro.sharding.logical import folded_axis_index, mesh_axis_size
 
 
 @functools.partial(jax.jit, static_argnames=("block", "mode"))
+@jax.named_scope("apsp")
 def apsp_blocked_segment(
     g: jax.Array, lo, hi, *, block: int = 512, mode: str = "auto"
 ):
@@ -64,16 +65,19 @@ def apsp_blocked_segment(
 
     def iteration(i, g):
         off = i * block
-        d = jax.lax.dynamic_slice(g, (off, off), (block, block))
-        d = ops.floyd_warshall(d, mode=mode)
-        r = jax.lax.dynamic_slice(g, (off, 0), (block, n))
-        c = jax.lax.dynamic_slice(g, (0, off), (n, block))
-        # Phase 2 fused: in-place panel updates min(R, D (x) R) /
-        # min(C, C (x) D) - no (b, n) min-plus intermediate
-        r = ops.minplus_panel_row(d, r, mode=mode)
-        c = ops.minplus_panel_col(c, d, mode=mode)
+        with jax.named_scope("diag"):
+            d = jax.lax.dynamic_slice(g, (off, off), (block, block))
+            d = ops.floyd_warshall(d, mode=mode)
+        with jax.named_scope("panels"):
+            r = jax.lax.dynamic_slice(g, (off, 0), (block, n))
+            c = jax.lax.dynamic_slice(g, (0, off), (n, block))
+            # Phase 2 fused: in-place panel updates min(R, D (x) R) /
+            # min(C, C (x) D) - no (b, n) min-plus intermediate
+            r = ops.minplus_panel_row(d, r, mode=mode)
+            c = ops.minplus_panel_col(c, d, mode=mode)
         # Phase 3 fused: min(G, C (x) R) without the (n, n) intermediate
-        return ops.minplus_update(g, c, r, mode=mode)
+        with jax.named_scope("update"):
+            return ops.minplus_update(g, c, r, mode=mode)
 
     return jax.lax.fori_loop(lo, hi, iteration, g)
 
@@ -104,6 +108,7 @@ def _masked_bcast_cols(local, off_in_shard, own, b, axis):
     return jax.lax.psum(sl, axis)
 
 
+@jax.named_scope("apsp")
 def _apsp_shard_body(
     g_loc, lo, hi, *, b, nr, nc, pd, pm, data_axis, model_axis, mode,
     split_panels=False,
@@ -126,47 +131,65 @@ def _apsp_shard_body(
         # --- panel broadcasts (the only communication) ---
         r_owner = off // nr          # data-group owning the block row
         c_owner = off // nc          # model-group owning the block column
-        row = _masked_bcast_rows(
-            g_loc, off - r_owner * nr, di == r_owner, b, data_axis
-        )                            # (b, nc) on every device
-        col = _masked_bcast_cols(
-            g_loc, off - c_owner * nc, mi == c_owner, b, model_axis
-        )                            # (nr, b)
-        # diagonal block, replicated everywhere: slice it out of `row`
-        loc_off = jnp.clip(off - c_owner * nc, 0, nc - b)
-        sl = jax.lax.dynamic_slice_in_dim(row, loc_off, b, axis=1)
-        diag = jax.lax.psum(jnp.where(mi == c_owner, sl, 0.0), model_axis)
+        with jax.named_scope("exchange"):
+            row = _masked_bcast_rows(
+                g_loc, off - r_owner * nr, di == r_owner, b, data_axis
+            )                        # (b, nc) on every device
+            col = _masked_bcast_cols(
+                g_loc, off - c_owner * nc, mi == c_owner, b, model_axis
+            )                        # (nr, b)
+            # diagonal block, replicated everywhere: slice it out of `row`
+            loc_off = jnp.clip(off - c_owner * nc, 0, nc - b)
+            sl = jax.lax.dynamic_slice_in_dim(row, loc_off, b, axis=1)
+            diag = jax.lax.psum(
+                jnp.where(mi == c_owner, sl, 0.0), model_axis
+            )
         # --- Phase 1: FW on the diagonal block (replicated compute) ---
-        diag = ops.floyd_warshall(diag, mode=mode)
+        with jax.named_scope("diag"):
+            diag = ops.floyd_warshall(diag, mode=mode)
         # --- Phase 2: panel updates ---
         if split_panels and b % pd == 0 and b % pm == 0:
             # fused split panels: each rank updates its 1/p slice in place
             # (min(slice, dslice (x) panel) via the seeded Phase-3 kernel)
             # and the group gathers - still no min-plus intermediate
             bs_r = b // pd
-            dslice = jax.lax.dynamic_slice_in_dim(diag, di * bs_r, bs_r, 0)
-            rseed = jax.lax.dynamic_slice_in_dim(row, di * bs_r, bs_r, 0)
-            row_part = ops.minplus_update(
-                rseed, dslice, row, mode=mode
-            )                                               # (b/pd, nc)
-            row = jax.lax.all_gather(
-                row_part, data_axis, axis=0, tiled=True
-            )                                               # (b, nc)
             bs_c = b // pm
-            dslice = jax.lax.dynamic_slice_in_dim(diag, mi * bs_c, bs_c, 1)
-            cseed = jax.lax.dynamic_slice_in_dim(col, mi * bs_c, bs_c, 1)
-            col_part = ops.minplus_update(
-                cseed, col, dslice, mode=mode
-            )                                               # (nr, b/pm)
-            col = jax.lax.all_gather(
-                col_part, model_axis, axis=1, tiled=True
-            )                                               # (nr, b)
+            with jax.named_scope("panels"):
+                dslice = jax.lax.dynamic_slice_in_dim(
+                    diag, di * bs_r, bs_r, 0
+                )
+                rseed = jax.lax.dynamic_slice_in_dim(
+                    row, di * bs_r, bs_r, 0
+                )
+                row_part = ops.minplus_update(
+                    rseed, dslice, row, mode=mode
+                )                                           # (b/pd, nc)
+            with jax.named_scope("exchange"):
+                row = jax.lax.all_gather(
+                    row_part, data_axis, axis=0, tiled=True
+                )                                           # (b, nc)
+            with jax.named_scope("panels"):
+                dslice = jax.lax.dynamic_slice_in_dim(
+                    diag, mi * bs_c, bs_c, 1
+                )
+                cseed = jax.lax.dynamic_slice_in_dim(
+                    col, mi * bs_c, bs_c, 1
+                )
+                col_part = ops.minplus_update(
+                    cseed, col, dslice, mode=mode
+                )                                           # (nr, b/pm)
+            with jax.named_scope("exchange"):
+                col = jax.lax.all_gather(
+                    col_part, model_axis, axis=1, tiled=True
+                )                                           # (nr, b)
         else:
             # Phase 2 fused in-place panel updates (no intermediate)
-            row = ops.minplus_panel_row(diag, row, mode=mode)  # (b, nc)
-            col = ops.minplus_panel_col(col, diag, mode=mode)  # (nr, b)
+            with jax.named_scope("panels"):
+                row = ops.minplus_panel_row(diag, row, mode=mode)  # (b, nc)
+                col = ops.minplus_panel_col(col, diag, mode=mode)  # (nr, b)
         # --- Phase 3: fused rank-b min-plus update of the local tile ---
-        return ops.minplus_update(g_loc, col, row, mode=mode)
+        with jax.named_scope("update"):
+            return ops.minplus_update(g_loc, col, row, mode=mode)
 
     return jax.lax.fori_loop(lo, hi, iteration, g_loc)
 

@@ -11,12 +11,15 @@ distributed pipeline can run inside a single shard_map region.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 
 @jax.jit
+@jax.named_scope("center")
 def double_center(a_sq: jax.Array) -> jax.Array:
     """-1/2 H (A^{o2}) H for a full (n, n) squared-distance matrix."""
     col_mean = jnp.mean(a_sq, axis=0, keepdims=True)   # (1, n)
@@ -25,6 +28,7 @@ def double_center(a_sq: jax.Array) -> jax.Array:
     return -0.5 * (a_sq - col_mean - row_mean + grand)
 
 
+@jax.named_scope("center")
 def double_center_local(a_sq_loc, *, data_axis: str, model_axis: str, n: int):
     """shard_map body: local (nr, nc) tile of A^{o2} -> centered tile.
 
@@ -45,13 +49,21 @@ def double_center_local(a_sq_loc, *, data_axis: str, model_axis: str, n: int):
 
 def double_center_sharded(a_sq: jax.Array, mesh: Mesh,
                           data_axis: str = "data", model_axis: str = "model"):
-    n = a_sq.shape[0]
+    fn = _make_double_center_sharded(mesh, a_sq.shape[0], data_axis,
+                                     model_axis)
+    return fn(a_sq)
+
+
+@functools.lru_cache(maxsize=None)
+def _make_double_center_sharded(mesh: Mesh, n: int, data_axis: str,
+                                model_axis: str):
+    """The jitted shard_map of :func:`double_center_sharded`, built once
+    per mesh and n."""
     fn = jax.shard_map(
-        lambda t: double_center_local(
-            t, data_axis=data_axis, model_axis=model_axis, n=n
-        ),
+        functools.partial(double_center_local, data_axis=data_axis,
+                          model_axis=model_axis, n=n),
         mesh=mesh,
         in_specs=P(data_axis, model_axis),
         out_specs=P(data_axis, model_axis),
     )
-    return jax.jit(fn)(a_sq)
+    return jax.jit(fn)

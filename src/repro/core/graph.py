@@ -21,6 +21,7 @@ import jax.numpy as jnp
 
 
 @functools.partial(jax.jit, static_argnames=("n",))
+@jax.named_scope("graph")
 def knn_to_graph(dists: jax.Array, idx: jax.Array, *, n: int) -> jax.Array:
     """(n, k) squared kNN distances + indices -> dense (n, n) graph.
 
@@ -57,6 +58,7 @@ def connected_components_lower_bound(g: jax.Array, iters: int = 32):
 
 
 @functools.partial(jax.jit, static_argnames=("n", "deg"))
+@jax.named_scope("csr_graph")
 def _padded_csr_device(dists, idx, *, n: int, deg: int):
     """Fixed-shape XLA form of the symmetrize/dedupe/bucket pipeline.
 

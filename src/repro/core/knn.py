@@ -52,6 +52,7 @@ def _fold_topk(best_d, best_i, new_d, new_i, k: int):
 
 
 @functools.partial(jax.jit, static_argnames=("k", "block", "mode"))
+@jax.named_scope("knn")
 def knn_blocked(
     x: jax.Array, *, k: int, block: int = 1024, mode: str = "auto"
 ):
@@ -169,13 +170,29 @@ def knn_ring(
         # sentinel rows so every shard holds the same local count; their
         # columns are masked via n_valid and their rows stripped below
         x = jnp.pad(x, ((0, pad), (0, 0)))
-    n = n_orig + pad
+    fn = _make_knn_ring(
+        mesh, n_orig, n_orig + pad, k, row_axis, feat_axis, split_axis,
+        gather_features, mode,
+    )
+    d, i = fn(x)
+    return (d[:n_orig], i[:n_orig]) if pad else (d, i)
+
+
+@functools.lru_cache(maxsize=None)
+def _make_knn_ring(
+    mesh, n_orig, n, k, row_axis, feat_axis, split_axis, gather_features,
+    mode,
+):
+    """The jitted ring of :func:`knn_ring`, built once per mesh, shape and
+    parameters so that every later fit reuses its executable."""
+    p = mesh.shape[row_axis]
     local = n // p
     perm = [(i, (i + 1) % p) for i in range(p)]
     n_split = mesh.shape[split_axis] if split_axis else 1
     assert p % n_split == 0
     steps = p // n_split
 
+    @jax.named_scope("knn")
     def shard_fn(xs):
         # xs: (local, D_local) slab of this shard
         me = jax.lax.axis_index(row_axis)
@@ -249,5 +266,4 @@ def knn_ring(
         out_specs=(P(row_axis, None), P(row_axis, None)),
         check_vma=False,
     )
-    d, i = jax.jit(fn)(x)
-    return (d[:n_orig], i[:n_orig]) if pad else (d, i)
+    return jax.jit(fn)

@@ -81,7 +81,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import apsp as apsp_mod
-from repro.core import centering, graph, knn as knn_mod, spectral
+from repro.core import centering, graph, knn as knn_mod, spectral, telemetry
 from repro.core.artifacts import (
     SEGMENT_STATE_KEY,
     ArtifactStore,
@@ -293,6 +293,15 @@ class LocalBackend:
 
 
 @functools.lru_cache(maxsize=None)
+def _make_tiled_graph(tile_spec, n: int):
+    """The dense kNN graph, built straight into the mesh's tiles (one
+    executable per mesh and n)."""
+    return jax.jit(
+        functools.partial(graph.knn_to_graph, n=n), out_shardings=tile_spec
+    )
+
+
+@functools.lru_cache(maxsize=None)
 def _make_gather_rows(mesh):
     from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -345,10 +354,7 @@ class MeshBackend:
         )
 
     def graph(self, cfg: PipelineConfig, dists, idx, n: int):
-        return jax.jit(
-            functools.partial(graph.knn_to_graph, n=n),
-            out_shardings=self.tile_spec,
-        )(dists, idx)
+        return _make_tiled_graph(self.tile_spec, n)(dists, idx)
 
     def clamp(self, cfg: PipelineConfig, a):
         return jax.jit(clamp_disconnected, out_shardings=self.tile_spec)(a)
@@ -1228,13 +1234,15 @@ class ManifoldPipeline:
                 seglen = max(1, int(round(ckpt_secs / per_unit)))
                 lo += 1
             if self.checkpoint is not None and lo < total:
-                self._save_partial(i, stage, store, state, lo, total)
+                with telemetry.span("checkpoint"):
+                    self._save_partial(i, stage, store, state, lo, total)
         seglen = seglen or total
         while lo < total:
             hi = min(lo + seglen, total)
             state = stage.run_segment(ctx, store, state, lo, hi)
             if self.checkpoint is not None and hi < total:
-                self._save_partial(i, stage, store, state, hi, total)
+                with telemetry.span("checkpoint"):
+                    self._save_partial(i, stage, store, state, hi, total)
             lo = hi
         return stage.finalize(ctx, store, state)
 
@@ -1243,7 +1251,14 @@ class ManifoldPipeline:
 
         Returns the :class:`~repro.core.artifacts.ArtifactStore` holding
         the exported artifacts (a Mapping - ``art["embedding"]`` etc.).
+        The host spans ``repro:fit``, ``repro:stage:<name>`` and
+        ``repro:checkpoint`` cover the dispatch of the work: nothing here
+        waits for the device (see :mod:`repro.core.telemetry`).
         """
+        with telemetry.span("fit"):
+            return self._run(x, resume=resume)
+
+    def _run(self, x, *, resume: bool) -> ArtifactStore:
         backend = self.ctx.backend
         store = ArtifactStore()
         store.exports = self.exports
@@ -1287,22 +1302,25 @@ class ManifoldPipeline:
                     seg_lo = point.seg_lo
         for i in range(start, len(self.stages)):
             stage = self.stages[i]
-            if _is_resumable(stage):
-                out = self._run_resumable(
-                    i, stage, store,
-                    seg_state if i == start else None,
-                    seg_lo if i == start else 0,
-                )
-            else:
-                out = stage.run(self.ctx, store)
-            for k, v in out.items():
-                store.put(
-                    k, v, producer=stage.name,
-                    placement=backend.placement_of(v),
-                )
-            store.prune(self._live_after(i))
+            with telemetry.span("stage:" + stage.name):
+                if _is_resumable(stage):
+                    out = self._run_resumable(
+                        i, stage, store,
+                        seg_state if i == start else None,
+                        seg_lo if i == start else 0,
+                    )
+                else:
+                    out = stage.run(self.ctx, store)
+                for k, v in out.items():
+                    store.put(
+                        k, v, producer=stage.name,
+                        placement=backend.placement_of(v),
+                    )
+                store.prune(self._live_after(i))
             if self.checkpoint is not None:
-                self._save_boundary(i, stage, store)
+                with telemetry.span("checkpoint"):
+                    self._save_boundary(i, stage, store)
         if self.checkpoint is not None:
-            self.checkpoint.wait()
+            with telemetry.span("checkpoint"):
+                self.checkpoint.wait()
         return store

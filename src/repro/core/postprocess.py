@@ -10,6 +10,7 @@ import jax
 import jax.numpy as jnp
 
 
+@jax.named_scope("clamp")
 def clamp_disconnected(a: jax.Array) -> jax.Array:
     """Replace +inf geodesics (disconnected components) by 1.1x the graph
     diameter.  A no-op on connected graphs (the paper's k is chosen for a

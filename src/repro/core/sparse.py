@@ -152,6 +152,7 @@ def _solve_batch(
     return dist
 
 
+@jax.named_scope("sparse_geodesics")
 def _segment_rows(
     nbr, w, lm_idx, panel, lo, hi, delta, *,
     row0, ml: int, bs: int, bucket: int, bn: int, mode: str,
@@ -282,6 +283,7 @@ class PanelEmbedding(NamedTuple):
 
 
 @functools.partial(jax.jit, static_argnames=("d", "max_iter"))
+@jax.named_scope("sparse_embed")
 def landmark_mds_general(
     dl: jax.Array, lm_idx: jax.Array, *, d: int,
     max_iter: int = 100, tol: float = 1e-9,
@@ -321,6 +323,7 @@ def panel_row_mean_sq(panel: jax.Array) -> jax.Array:
 
 
 @functools.partial(jax.jit, static_argnames=("k",))
+@jax.named_scope("map")
 def map_new_points_panel(
     x_new, x_base, panel, pinv, mean2, *, k: int
 ):

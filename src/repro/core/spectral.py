@@ -37,6 +37,7 @@ def _sign_fix(q):
 
 
 @functools.partial(jax.jit, static_argnames=("d", "max_iter"))
+@jax.named_scope("eigen")
 def power_iteration(
     a: jax.Array, *, d: int, max_iter: int = 100, tol: float = 1e-9
 ) -> EigResult:
@@ -88,6 +89,7 @@ def matvec_sharded(a_loc, q, *, data_axis, model_axis, nc):
     return v
 
 
+@functools.lru_cache(maxsize=None)
 def make_power_iteration_sharded(
     mesh: Mesh,
     *,
@@ -98,12 +100,14 @@ def make_power_iteration_sharded(
     data_axis: str = "data",
     model_axis: str = "model",
 ):
-    """Returns jit'd fn(a_sharded) -> EigResult with replicated outputs."""
+    """Returns jit'd fn(a_sharded) -> EigResult with replicated outputs
+    (memoized: one executable per mesh, n and parameters)."""
     from repro.sharding.logical import mesh_axis_size
 
     pd, pm = mesh_axis_size(mesh, data_axis), mesh_axis_size(mesh, model_axis)
     nr, nc = n // pd, n // pm
 
+    @jax.named_scope("eigen")
     def shard_fn(a_loc):
         q0, _ = jnp.linalg.qr(jnp.eye(n, d, dtype=a_loc.dtype))
         q0 = _sign_fix(q0)

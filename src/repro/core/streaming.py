@@ -40,6 +40,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from repro.core import telemetry
 from repro.core.artifacts import VersionedArtifacts
 from repro.kernels import ops
 
@@ -68,6 +69,7 @@ def geodesic_row_mean_sq(a_base: jax.Array) -> jax.Array:
 
 
 @functools.partial(jax.jit, static_argnames=("k",))
+@jax.named_scope("map")
 def map_new_points(
     x_new: jax.Array,      # (m, D) stream arrivals
     x_base: jax.Array,     # (n, D) initial batch
@@ -229,6 +231,7 @@ def _make_map_new_points_sharded(
         )
     nr = n // pd
 
+    @jax.named_scope("map")
     def shard_fn(x_new, xb_loc, a_loc, y_base, mean_sq):
         geo = _geo_shard_body(
             x_new, xb_loc, a_loc, k, nr, data_axis, model_axis, mode
@@ -520,7 +523,8 @@ class StreamingMapper:
         The whole call serves from one captured version: an absorb
         landing mid-call cannot mix generations across chunks."""
         snap = self._versions.current
-        x_new = jnp.asarray(x_new)
+        with telemetry.span("map:put"):
+            x_new = jnp.asarray(x_new)
         m = x_new.shape[0]
         d = snap["embedding"].shape[1]
         if m == 0:

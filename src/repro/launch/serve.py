@@ -318,6 +318,8 @@ def serve_manifold(
                 [s["mean_batch"] for s in replica_stats]
             )) if reqs else float("nan"),
             "requests": reqs,
+            "queue_wait_s": sum(s["queue_wait_s"] for s in replica_stats),
+            "service_s": sum(s["service_s"] for s in replica_stats),
         }
     else:
         service = BatchedMapperService(
@@ -339,6 +341,7 @@ def serve_manifold(
             y_stream = np.concatenate([f.result() for f in futures], axis=0)
             t_serve = time.time() - t0
         stats = service.stats()
+    reqs_served = max(stats["requests"], 1)
 
     # quality in the *served* frame: the absorb republished the base
     # embedding (possibly with flipped eigenvector signs), and every
@@ -368,6 +371,9 @@ def serve_manifold(
         "points_per_s": n_stream / max(t_serve, 1e-9),
         "latency_p50_ms": stats["latency_p50_ms"],
         "latency_p99_ms": stats["latency_p99_ms"],
+        # each request's latency = its queue wait + its flush's service
+        "queue_wait_ms": 1e3 * stats["queue_wait_s"] / reqs_served,
+        "service_ms": 1e3 * stats["service_s"] / reqs_served,
         "mean_batch": stats["mean_batch"],
         "requests": stats["requests"],
         "procrustes_error": err,
@@ -525,6 +531,8 @@ def main():
             f"({out['points_per_s']:.0f} pts/s) "
             f"p50={out['latency_p50_ms']:.1f}ms "
             f"p99={out['latency_p99_ms']:.1f}ms "
+            f"wait={out['queue_wait_ms']:.1f}ms "
+            f"service={out['service_ms']:.1f}ms "
             f"mean_batch={out['mean_batch']:.1f} "
             f"absorbed={out['absorbed']} v{out['serving_version']} "
             f"err={out['procrustes_error']:.2e} "

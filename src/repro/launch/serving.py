@@ -26,7 +26,12 @@ too):
   (p50/p99) and batch occupancy over a bounded rolling window (memory
   stays flat under sustained traffic), plus lifetime request/point
   counters and sustained points/s - the numbers the serving benchmark
-  (``benchmarks/bench_serving.py``) reports.
+  (``benchmarks/bench_serving.py``) reports.  Lifetime time counters
+  split each request's latency into ``queue_wait_s`` (submit to the
+  start of its flush) and ``service_s`` (start of its flush to its
+  reply), and each flush into ``pack_s``, ``map_call_s``, ``fetch_s``
+  and ``reply_s``; the same phases are host spans
+  (``repro:serve:*``, :mod:`repro.core.telemetry`).
 * Write path: :meth:`BatchedMapperService.submit_absorb` coordinates
   geodesic absorbs (:meth:`StreamingMapper.absorb`) with the read path -
   updates run on the scheduler thread *between* flushes (never
@@ -46,6 +51,15 @@ import time
 from concurrent.futures import Future
 
 import numpy as np
+
+from repro.core import telemetry
+
+#: lifetime time counters of :meth:`BatchedMapperService.stats`, seconds:
+#: summed over requests (queue wait, service) or over flushes (the rest)
+TIME_COUNTERS = (
+    "queue_wait_s", "service_s", "pack_s", "map_call_s", "fetch_s",
+    "reply_s",
+)
 
 
 class AbsorbRejected(RuntimeError):
@@ -141,6 +155,7 @@ class BatchedMapperService:
         self._n_batches = 0
         self._n_absorbed = 0
         self._n_absorb_calls = 0
+        self._times = dict.fromkeys(TIME_COUNTERS, 0.0)
 
     # --------------------------------------------------------- lifecycle --
 
@@ -248,29 +263,8 @@ class BatchedMapperService:
                     ):
                         return
                     continue
-            batch = [first]
-            count = first.x.shape[0]
-            deadline = first.t_submit + self.max_latency_s
-            while count < self.max_batch:
-                timeout = deadline - time.monotonic()
-                try:
-                    # past the deadline, still drain whatever is already
-                    # queued (a slow flush must not collapse the next
-                    # batch to size 1 under backlog)
-                    req = (
-                        self._queue.get(timeout=timeout)
-                        if timeout > 0
-                        else self._queue.get_nowait()
-                    )
-                except queue.Empty:
-                    break
-                if count + req.x.shape[0] > self.max_batch:
-                    # would overflow the fixed compiled shape: flush now,
-                    # open the next batch with this request
-                    pending = req
-                    break
-                batch.append(req)
-                count += req.x.shape[0]
+            with telemetry.span("serve:coalesce"):
+                batch, pending = self._coalesce(first)
             self._dispatch(batch)
             if pending is None and self._queue.empty():
                 # between flushes with no backlog: absorb window
@@ -281,6 +275,33 @@ class BatchedMapperService:
                 # batching deadline, run exactly one between flushes
                 # (bounding the per-flush read-latency impact)
                 self._run_absorbs(limit=1)
+
+    def _coalesce(self, first: _Request):
+        """-> (the batch opened by ``first``, the request that would
+        have overflowed it or None)."""
+        batch = [first]
+        count = first.x.shape[0]
+        deadline = first.t_submit + self.max_latency_s
+        while count < self.max_batch:
+            timeout = deadline - time.monotonic()
+            try:
+                # past the deadline, still drain whatever is already
+                # queued (a slow flush must not collapse the next
+                # batch to size 1 under backlog)
+                req = (
+                    self._queue.get(timeout=timeout)
+                    if timeout > 0
+                    else self._queue.get_nowait()
+                )
+            except queue.Empty:
+                return batch, None
+            if count + req.x.shape[0] > self.max_batch:
+                # would overflow the fixed compiled shape: flush now,
+                # open the next batch with this request
+                return batch, req
+            batch.append(req)
+            count += req.x.shape[0]
+        return batch, None
 
     def _dispatch(self, batch: list[_Request]):
         """Run one coalesced flush: inline at depth 1, else on the worker
@@ -334,7 +355,8 @@ class BatchedMapperService:
             if limit is not None:
                 limit -= 1
             try:
-                report = self.mapper.absorb(x)
+                with telemetry.span("serve:absorb"):
+                    report = self.mapper.absorb(x)
             except Exception as e:
                 fut.set_exception(e)
                 continue
@@ -344,14 +366,22 @@ class BatchedMapperService:
             fut.set_result(report)
 
     def _flush(self, reqs: list[_Request]):
+        t_flush = time.monotonic()
         try:
-            xs = np.concatenate([r.x for r in reqs], axis=0)
-            n = xs.shape[0]
-            if self.pad_batches and 0 < n < self.max_batch:
-                pad = np.zeros((self.max_batch - n, xs.shape[1]), xs.dtype)
-                y = np.asarray(self.mapper(np.concatenate([xs, pad])))[:n]
-            else:
-                y = np.asarray(self.mapper(xs))
+            with telemetry.span("serve:pack"):
+                xs = np.concatenate([r.x for r in reqs], axis=0)
+                n = xs.shape[0]
+                if self.pad_batches and 0 < n < self.max_batch:
+                    pad = np.zeros(
+                        (self.max_batch - n, xs.shape[1]), xs.dtype
+                    )
+                    xs = np.concatenate([xs, pad])
+            t_packed = time.monotonic()
+            with telemetry.span("serve:map"):
+                out = self.mapper(xs)
+            t_mapped = time.monotonic()
+            with telemetry.span("serve:fetch"):
+                y = np.asarray(out)[:n]
         except Exception as e:
             # a failed flush fails every request in it, through the future
             # each caller reads: nothing escapes to the scheduler thread or
@@ -360,13 +390,24 @@ class BatchedMapperService:
                 r.future.set_exception(e)
             return
         t_done = time.monotonic()
-        off = 0
-        for r in reqs:
-            g = r.x.shape[0]
-            r.future.set_result(y[off : off + g])
-            off += g
+        with telemetry.span("serve:reply"):
+            off = 0
+            for r in reqs:
+                g = r.x.shape[0]
+                r.future.set_result(y[off : off + g])
+                off += g
+        t_replied = time.monotonic()
         with self._lock:
-            self._latencies.extend(t_done - r.t_submit for r in reqs)
+            lat = [t_done - r.t_submit for r in reqs]
+            self._latencies.extend(lat)
+            service = len(reqs) * (t_done - t_flush)
+            times = self._times
+            times["queue_wait_s"] += sum(lat) - service
+            times["service_s"] += service
+            times["pack_s"] += t_packed - t_flush
+            times["map_call_s"] += t_mapped - t_packed
+            times["fetch_s"] += t_done - t_mapped
+            times["reply_s"] += t_replied - t_done
             self._batch_sizes.append(n)
             self._n_requests += len(reqs)
             self._n_points += n
@@ -387,6 +428,7 @@ class BatchedMapperService:
             absorbed = self._n_absorbed
             absorb_calls = self._n_absorb_calls
             inflight_peak = self._inflight_peak
+            times = dict(self._times)
             wall = (
                 (self._t_last - self._t_first)
                 if self._t_first is not None and self._t_last is not None
@@ -402,6 +444,7 @@ class BatchedMapperService:
                 "absorb_calls": absorb_calls,
                 "pipeline_depth": self.pipeline_depth,
                 "inflight_peak": inflight_peak,
+                **times,
             }
         return {
             "requests": n_requests,
@@ -416,4 +459,5 @@ class BatchedMapperService:
             "absorb_calls": absorb_calls,
             "pipeline_depth": self.pipeline_depth,
             "inflight_peak": inflight_peak,
+            **times,
         }
