@@ -450,12 +450,8 @@ def bench_frontier(smoke: bool = False):
 
     # 2. autotuned knobs model no slower than the clamped static default
     cfg, cost = autotune.best_frontier_config(n, deg, m)
-    dflt = autotune.FrontierConfig(
-        min(autotune.FRONTIER_DEFAULT.bs, autotune.frontier_batch(n, m)),
-        min(autotune.FRONTIER_DEFAULT.bn, n),
-        autotune.FRONTIER_DEFAULT.bucket,
-    )
-    dcost = autotune.frontier_cost(n, deg, dflt)
+    dflt = autotune.frontier_default(n, m)
+    dcost = autotune.frontier_cost(n, deg, m, dflt)
     assert cost.time_s <= dcost.time_s * (1.0 + 1e-9), (
         f"autotuned frontier config {cfg} models slower than the static "
         f"default {dflt}"

@@ -28,7 +28,11 @@ The knobs (``bs`` sources per launch, ``bn`` node tile, ``bucket``
 sweeps per convergence check) come from the frontier autotuner
 (:func:`repro.kernels.autotune.frontier_config`); ``delta`` is derived
 from the mean finite edge weight so a round of ``bucket`` sweeps and the
-threshold bound advance at the same rate.
+threshold bound advance at the same rate.  A batch's tentative distances
+are nodes-major, (n, bs) with the sources on the lanes, for its whole
+solve; only its settled rows are transposed into the (m, n) panel.  The
+settled panel is the minimum over paths of left-to-right float32 path
+sums, so it does not depend on ``bs``, ``bucket`` or ``delta``.
 
 Mesh execution is embarrassingly parallel: landmark rows are sharded over
 the *folded* (data, model) axis (every device, not every data row, owns
@@ -121,14 +125,16 @@ def _solve_batch(
 ):
     """Exact SSSP for one fixed-shape source batch.
 
-    src (bs,) int32 node indices -> (bs, n) geodesic distances (+inf where
-    unreachable).  See the module docstring for the settled-iff-exact
-    argument; ``max_rounds`` is a runaway backstop only (the bound check
-    fails before it in any terminating run)."""
+    src (bs,) int32 node indices -> (n, bs) geodesic distances (+inf where
+    unreachable), nodes-major: the sources ride the lanes for the whole
+    loop, and the caller transposes once per batch.  See the module
+    docstring for the settled-iff-exact argument; ``max_rounds`` is a
+    runaway backstop only (the bound check fails before it in any
+    terminating run)."""
     bs = src.shape[0]
     n = nbr.shape[0]
-    dist = jnp.full((bs, n), jnp.inf, dtype=jnp.float32)
-    dist = dist.at[jnp.arange(bs), src].set(0.0)
+    dist = jnp.full((n, bs), jnp.inf, dtype=jnp.float32)
+    dist = dist.at[src, jnp.arange(bs)].set(0.0)
 
     def cond(carry):
         _, t, done = carry
@@ -171,7 +177,7 @@ def _segment_rows(
             src, nbr, w, delta,
             bucket=bucket, bn=bn, mode=mode, max_rounds=max_rounds,
         )
-        return jax.lax.dynamic_update_slice(panel, d, (start, 0))
+        return jax.lax.dynamic_update_slice(panel, d.T, (start, 0))
 
     return jax.lax.fori_loop(lo, hi, one_batch, panel)
 
@@ -410,8 +416,8 @@ class LandmarkSelectStage:
 class SparseGeodesicStage:
     """Exact landmark geodesics over the CSR graph, as a ResumableStage.
 
-    Units are landmark batches (batch size from the frontier autotuner's
-    batch cap), state is the growing (m, n) panel — so
+    Units are landmark batches (the frontier autotuner's lane-width
+    batch), state is the growing (m, n) panel — so
     checkpoint/resume and ``--checkpoint-secs`` calibration work through
     the engine unchanged, and a kill mid-panel re-enters at the recorded
     batch.  ``segment_requires`` keeps the CSR graph + landmark set in

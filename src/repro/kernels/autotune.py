@@ -442,76 +442,108 @@ def tiles_for(op: str, m: int, n: int, k: int, *, itemsize: int = 4) -> dict:
 # Knobs of the sparse frontier-relaxation kernel (repro.kernels.frontier)
 # and its SSSP driver (repro.core.sparse.sssp_panel).  Unlike the min-plus
 # family, the tunables span two layers: ``bn`` is the kernel's node-tile
-# width, while ``bs`` (sources per launch) and ``bucket`` (masked sweeps
+# height, while ``bs`` (sources per launch) and ``bucket`` (masked sweeps
 # per convergence check) belong to sssp_panel — they are tuned together
-# because the per-sweep gathered (bs, deg, n) block couples them.
+# because the per-sweep gathered (deg, n, bs) block couples them.
 
 ENV_FRONTIER_TILES = "REPRO_FRONTIER_TILES"
 ENV_FRONTIER_AUTOTUNE = "REPRO_FRONTIER_AUTOTUNE"
 
-#: prior on sweeps-to-settle (the kNN graph's hop diameter); only the
-#: *ratio* of check cost to sweep cost times this prior steers ``bucket``,
-#: so a mis-estimate moves the knob logarithmically.
+#: prior on the rounds a batch runs: its *slowest* source's sweeps to
+#: settle (about the kNN graph's hop eccentricity, which the max over a
+#: batch's sources saturates at); only the ratio of check cost to sweep
+#: cost times this prior steers ``bucket``, so a mis-estimate moves the
+#: knob logarithmically.
 FRONTIER_SWEEPS_PRIOR = 32
+
+#: lane width of the chip's (8, 128) register and HBM tiling: the
+#: frontier's sources ride the lanes, so a batch of fewer sources still
+#: moves and computes a full lane tile
+LANES = 128
+
+#: fixed cost of one gathered row, on top of its bytes (the gather is
+#: charged per row: deg * n rows a sweep whatever their width).  On a
+#: TPU v5e a sweep at n = 40960, deg = 20, bs = 128 (819200 rows of
+#: 512 B) took 2.05 ms, 0.43 ms more than its 1.32 GB at the HBM peak.
+FRONTIER_GATHER_ROW_S = 5e-10
 
 
 class FrontierConfig(NamedTuple):
     """Static knobs of one sparse-geodesic solve."""
 
-    bs: int      # landmark sources per kernel launch
-    bn: int      # node columns per grid step
+    bs: int      # landmark sources per kernel launch (the lanes)
+    bn: int      # node rows per grid step
     bucket: int  # masked sweeps between convergence checks
 
 
-FRONTIER_DEFAULT = FrontierConfig(bs=8, bn=1024, bucket=4)
+FRONTIER_DEFAULT = FrontierConfig(bs=LANES, bn=256, bucket=4)
+
+
+def _lanes(s: int) -> int:
+    """Lanes an s-wide minor dim occupies in the chip's tiling."""
+    return -(-s // LANES) * LANES
+
+
+def frontier_landmark_s(
+    n: int, m: int, cfg: FrontierConfig, sweep_s: float, *,
+    itemsize: int = 4, hbm_bw: float | None = None,
+) -> float:
+    """Panel time per landmark of m sources solved in ``cfg.bs``-wide
+    batches, from one sweep's time: each batch runs as many rounds as
+    its slowest source needs (:data:`FRONTIER_SWEEPS_PRIOR`, whatever
+    ``bs``) plus the expected (bucket-1)/2 sweeps of bucket overshoot,
+    and reads its (n, bs) pair once per ``bucket`` sweeps for the
+    convergence check; the m sources take ``ceil(m / bs)`` batches (the
+    last shifted back, its overlap recomputed)."""
+    bw = hbm_bw if hbm_bw else chip().hbm_bw
+    check_s = itemsize * 2 * n * _lanes(cfg.bs) / bw
+    rounds = FRONTIER_SWEEPS_PRIOR
+    batch_s = (sweep_s * (rounds + (cfg.bucket - 1) / 2.0)
+               + check_s * rounds / cfg.bucket)
+    m = max(m, 1)
+    return -(-m // min(cfg.bs, m)) * batch_s / m
 
 
 def frontier_cost(
-    n: int, deg: int, cfg: FrontierConfig, *, itemsize: int = 4,
+    n: int, deg: int, m: int, cfg: FrontierConfig, *, itemsize: int = 4,
     hbm_bw: float | None = None, launch_s: float = 0.0,
 ) -> Cost:
-    """Roofline terms for one *effective* masked sweep of the frontier
-    kernel: the sweep itself plus its amortized share of the convergence
-    check and the expected bucket-overshoot waste.
+    """Roofline terms of the frontier solve of m landmark sources, with
+    sources on the lanes, per landmark.
 
-    Per sweep the VPU does 3 ops per (source, node, lane) triple (mask
-    select, add, running min); HBM moves the (bs, deg, n) gathered block
-    three times (the gather's reads and write, the kernel's read), the
-    (bs, n) distances in twice and out once, plus the (n, deg) nbr/w
-    pair.  The convergence check
-    is an (bs, n) reduction charged once per ``bucket`` sweeps; overshoot
-    charges the (bucket-1)/2 sweeps expected to run past the settle point,
-    spread over :data:`FRONTIER_SWEEPS_PRIOR` productive sweeps.
+    One sweep: the XLA row gather pulls deg * n rows of the (n, bs)
+    distances into the (deg, n, bs) block, charged per row
+    (:data:`FRONTIER_GATHER_ROW_S`) plus its bytes read and written; the
+    kernel reads that block, the (n, deg) weights and the (n, bs) seed
+    and writes the (n, bs) result; the VPU does 3 ops (mask select, add,
+    running min) per (node, slot, lane).  Every lane-minor array moves
+    and computes whole 128-lane tiles (:func:`_lanes`), so a batch under
+    the lane width costs a full-width sweep.
 
-    ``time_s`` is normalized **per landmark source** (divided by ``bs``)
-    so configs with different batch sizes are comparable: a bigger batch
-    amortizes the (n, deg) nbr/w stream over more sources.
+    ``time_s`` is the panel time per landmark
+    (:func:`frontier_landmark_s`: batches, each as long as its slowest
+    source), so configs with different batch sizes are comparable;
+    ``hbm_bytes`` and ``hbm_s`` are one sweep's.
     """
-    bs, bn, bucket = cfg
     hw = chip()
     bw = hbm_bw if hbm_bw else hw.hbm_bw
-    lane_fill = min(bn, 128) / 128.0
-    sublane_fill = min(deg, 8) / 8.0
-    compute_s = (3.0 * bs * n * deg) / (
-        hw.vpu_ops * lane_fill * sublane_fill
-    )
+    lanes = _lanes(cfg.bs)
+    rows = deg * n
+    compute_s = 3.0 * rows * lanes / hw.vpu_ops
     hbm_bytes = itemsize * (
-        3 * bs * deg * n  # gathered block: gather reads + write, kernel read
-        + 2 * bs * n      # distance reads (gather source, seed)
-        + 2 * n * deg     # nbr + w stream
-        + bs * n          # output write
+        3 * rows * lanes     # gathered block: gather read + write, kernel read
+        + n * _lanes(deg)    # w, its deg-wide rows lane-padded
+        + 2 * n * lanes      # seed read, output write
+        + n * deg            # nbr, read by the gather
     )
     hbm_s = hbm_bytes / bw
-    sweep_s = max(compute_s, hbm_s) + launch_s
-    check_s = itemsize * bs * n / bw
-    time_s = (
-        sweep_s * (1.0 + (bucket - 1) / (2.0 * FRONTIER_SWEEPS_PRIOR))
-        + check_s / bucket
-    ) / bs
-    # double-buffered (bs, deg, bn) gathered tiles plus the masked copy,
-    # double-buffered w tiles, double-buffered seed + output tiles
-    vmem = itemsize * (
-        3 * bs * deg * bn + 2 * deg * bn + 4 * bs * bn
+    sweep_s = rows * FRONTIER_GATHER_ROW_S + max(compute_s, hbm_s) + launch_s
+    time_s = frontier_landmark_s(n, m, cfg, sweep_s, itemsize=itemsize,
+                                 hbm_bw=bw)
+    # double-buffered (deg, bn, bs) gathered tiles, (bn, deg) weight tiles
+    # and (bn, bs) seed + output tiles, plus the running min and one slot
+    vmem = itemsize * cfg.bn * (
+        2 * deg * lanes + 2 * _lanes(deg) + 4 * lanes + 2 * lanes
     )
     return Cost(
         time_s=time_s,
@@ -522,35 +554,37 @@ def frontier_cost(
     )
 
 
-def frontier_batch(n: int, m: int, *, itemsize: int = 4) -> int:
-    """Largest power-of-two landmark batch (at most 64) whose (bs, n)
-    distance block is at most half the VMEM budget — which bounds the
-    gathered (bs, deg, n) block each sweep streams through HBM at deg
-    times that.  Single source of the batch cap sssp_panel and the stage
-    segmentation both use (units = ceil(m / frontier_batch))."""
-    cap = max(1, (chip().vmem_bytes // 4) // max(1, n * itemsize))
-    bs = 1
-    while bs * 2 <= min(cap, m, 64):
-        bs *= 2
-    return bs
+def frontier_batch(m: int) -> int:
+    """Landmark sources per batch: the lane width, or all m sources
+    where fewer (a full-dim lane block).  Single source of the batch
+    sssp_panel and the stage segmentation both use (units = ceil(m /
+    frontier_batch))."""
+    return max(1, min(m, LANES))
 
 
 def frontier_candidates(
     n: int, deg: int, m: int
 ) -> Iterator[FrontierConfig]:
-    """Enumerate frontier configs: power-of-two source batches up to the
-    batch cap, node tiles in multiples of 128, the chip's lane tiling
-    (ops.py pads n to a multiple, so no divisibility constraint), buckets
-    1..16."""
-    bs_cap = frontier_batch(n, m)
-    for bs in (1, 2, 4, 8, 16, 32, 64):
-        if bs > bs_cap:
-            break
-        for bn in (128, 256, 512, 1024, 2048, 4096):
-            if bn > n and bn != 128:
-                continue
-            for bucket in (1, 2, 4, 8, 16):
-                yield FrontierConfig(bs, min(bn, n), bucket)
+    """Enumerate frontier configs: the lane-width batch
+    (:func:`frontier_batch`; a narrower one moves and computes the same
+    lane tiles for fewer sources, in more batches), node tiles of 64 to
+    2048 rows (multiples of the chip's 8-row sublane tiling; ops.py pads
+    n to a multiple, so no divisibility constraint), buckets 1..16."""
+    bs = frontier_batch(m)
+    for bn in (64, 128, 256, 512, 1024, 2048):
+        if bn > n and bn != 64:
+            continue
+        for bucket in (1, 2, 4, 8, 16):
+            yield FrontierConfig(bs, min(bn, n), bucket)
+
+
+def frontier_default(n: int, m: int) -> FrontierConfig:
+    """:data:`FRONTIER_DEFAULT` clamped to the problem."""
+    return FrontierConfig(
+        min(FRONTIER_DEFAULT.bs, frontier_batch(m)),
+        min(FRONTIER_DEFAULT.bn, n),
+        FRONTIER_DEFAULT.bucket,
+    )
 
 
 @functools.lru_cache(maxsize=4096)
@@ -567,16 +601,12 @@ def best_frontier_config(
     fallback = None
     seen = set()
     budget = vmem_budget()
-    dflt = FrontierConfig(
-        min(FRONTIER_DEFAULT.bs, frontier_batch(n, m)),
-        min(FRONTIER_DEFAULT.bn, n),
-        FRONTIER_DEFAULT.bucket,
-    )
+    dflt = frontier_default(n, m)
     for cfg in list(frontier_candidates(n, deg, m)) + [dflt]:
         if cfg in seen:
             continue
         seen.add(cfg)
-        cost = frontier_cost(n, deg, cfg, itemsize=itemsize,
+        cost = frontier_cost(n, deg, m, cfg, itemsize=itemsize,
                              hbm_bw=hbm_bw, launch_s=launch_s)
         fkey = (cost.vmem_bytes, cost.time_s)
         if fallback is None or fkey < fallback[0]:
@@ -604,8 +634,8 @@ def resolve_frontier_config(
     provenance (same ordering as :func:`resolve_tiles`):
 
     1. ``REPRO_FRONTIER_TILES=bs,bn,bucket`` — pinned.
-    2. ``REPRO_FRONTIER_AUTOTUNE=0`` — the static default, batch clamped
-       to :func:`frontier_batch`.
+    2. ``REPRO_FRONTIER_AUTOTUNE=0`` — the static default, clamped to
+       the problem (:func:`frontier_default`).
     3. The measured-calibration layer (persisted winner / fresh sweep /
        corrected-constant re-rank).
     4. Otherwise the cached analytic sweep
@@ -617,11 +647,7 @@ def resolve_frontier_config(
     if os.environ.get(ENV_FRONTIER_AUTOTUNE, "1").lower() in (
         "0", "false", "off"
     ):
-        return FrontierConfig(
-            min(FRONTIER_DEFAULT.bs, frontier_batch(n, m)),
-            min(FRONTIER_DEFAULT.bn, n),
-            FRONTIER_DEFAULT.bucket,
-        ), "default"
+        return frontier_default(n, m), "default"
     measure = _measure_layer()
     if measure.active():
         got = measure.resolve_frontier(n, deg, m)

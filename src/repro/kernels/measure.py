@@ -555,10 +555,11 @@ def calibrate_frontier(
     """Measured frontier knobs for one sparse-geodesic solve.
 
     The kernel-level knobs (bs, bn) are measured directly — one masked
-    sweep of a synthetic (bs, n) panel over a synthetic padded-CSR graph,
-    normalized per source — while ``bucket`` (a driver-level amortization
-    knob the single sweep cannot observe) keeps the same analytic
-    amortization formula, applied to the *measured* sweep time."""
+    sweep of a synthetic nodes-major (n, bs) batch over a synthetic
+    padded-CSR graph — while the batch rounds and ``bucket`` (solver-level
+    terms the single sweep cannot observe) keep the analytic
+    :func:`repro.kernels.autotune.frontier_landmark_s`, applied to the
+    *measured* sweep time and normalized per landmark."""
     dims = (n, deg, m)
 
     def validate(raw):
@@ -582,16 +583,12 @@ def calibrate_frontier(
 
         ranked = []
         for cfg in autotune.frontier_candidates(n, deg, m):
-            cost = autotune.frontier_cost(n, deg, cfg, itemsize=itemsize)
+            cost = autotune.frontier_cost(n, deg, m, cfg, itemsize=itemsize)
             if cost.vmem_bytes > autotune.vmem_budget():
                 continue
             ranked.append((cost.time_s, cfg, cost))
         ranked.sort(key=lambda t: t[0])
-        dflt = FrontierConfig(
-            min(autotune.FRONTIER_DEFAULT.bs, autotune.frontier_batch(n, m)),
-            min(autotune.FRONTIER_DEFAULT.bn, n),
-            autotune.FRONTIER_DEFAULT.bucket,
-        )
+        dflt = autotune.frontier_default(n, m)
         entries, seen = [], set()
         for _, cfg, cost in ranked[:TOP_K]:
             if cfg not in seen:
@@ -599,7 +596,7 @@ def calibrate_frontier(
                 entries.append((cfg, cost))
         if dflt not in seen:
             entries.append(
-                (dflt, autotune.frontier_cost(n, deg, dflt,
+                (dflt, autotune.frontier_cost(n, deg, m, dflt,
                                               itemsize=itemsize))
             )
 
@@ -610,7 +607,7 @@ def calibrate_frontier(
             key = (cfg.bs, cfg.bn)
             if key not in sweep_times:
                 dist = jnp.asarray(
-                    rng.uniform(0.0, 5.0, (cfg.bs, n)), jnp.float32
+                    rng.uniform(0.0, 5.0, (n, cfg.bs)), jnp.float32
                 )
                 bn = cfg.bn
                 # jit once per (bs, bn), outside the timed callable
@@ -622,17 +619,12 @@ def calibrate_frontier(
                 sweep_times[key] = _time_fn(
                     lambda d=dist, j=jitted: j(d)
                 )
-            # per-source metric: measured sweep + the modeled bucket
-            # amortization (check cost + expected overshoot), as in
+            # per-landmark metric: measured sweep + the modeled batch
+            # rounds (slowest source, bucket overshoot, checks), as
             # autotune.frontier_cost but with the sweep term measured
-            t_sweep = sweep_times[key]
-            check_s = itemsize * cfg.bs * n / autotune.chip().hbm_bw
-            t = (
-                t_sweep
-                * (1.0 + (cfg.bucket - 1)
-                   / (2.0 * autotune.FRONTIER_SWEEPS_PRIOR))
-                + check_s / cfg.bucket
-            ) / cfg.bs
+            t = autotune.frontier_landmark_s(
+                n, m, cfg, sweep_times[key], itemsize=itemsize
+            )
             timed.append((cfg, t, cost))
         sweep_s = time.perf_counter() - t0
         win_cfg, win_t, _ = min(timed, key=lambda t: t[1])

@@ -281,18 +281,20 @@ def minplus_border(e, a, *, mode: str = "auto", **tile_kw):
 
 
 def frontier_relax(dist, nbr, w, hi, *, mode: str = "auto", **tile_kw):
-    """One masked frontier-relaxation sweep over the padded-CSR graph:
-    O[q,j] = min(D[q,j], min_d where(D[q, nbr[j,d]] < hi) + w[j,d]).
+    """One masked frontier-relaxation sweep over the padded-CSR graph,
+    nodes-major: O[j,q] = min(D[j,q], min_d where(D[nbr[j,d], q] < hi)
+    + w[j,d]).
 
-    dist (s, n), nbr/w (n, deg), hi scalar -> (s, n).  The only tile knob
-    is ``bn`` (node columns per grid step); without it the frontier
-    autotuner picks per-shape (``REPRO_FRONTIER_TILES=bs,bn,bucket`` pins
-    all three driver knobs, :func:`repro.kernels.autotune
-    .frontier_config`).  ``n`` is padded internally to a ``bn`` multiple
-    with +inf-weight self-edges, so padded lanes never win the min and
-    real columns are bit-identical to the unpadded oracle.
+    dist (n, s) with the s sources on the lanes, nbr/w (n, deg), hi
+    scalar -> (n, s).  The only tile knob is ``bn`` (node rows per grid
+    step); without it the frontier autotuner picks per-shape
+    (``REPRO_FRONTIER_TILES=bs,bn,bucket`` pins all three solver knobs,
+    :func:`repro.kernels.autotune.frontier_config`).  ``n`` is padded
+    internally to a ``bn`` multiple with +inf-weight self-edges, so
+    padded rows never win the min and real rows are bit-identical to
+    the unpadded oracle.
     """
-    s, n = dist.shape
+    n, s = dist.shape
     deg = nbr.shape[1]
     problems = []
     unknown = set(tile_kw) - {"bn"}
@@ -305,7 +307,7 @@ def frontier_relax(dist, nbr, w, hi, *, mode: str = "auto", **tile_kw):
         problems.append(f"tile bn={bn!r} must be a positive int")
     if problems:
         raise ValueError(
-            f"frontier_relax: invalid tile override for ({s}, {n}): "
+            f"frontier_relax: invalid tile override for ({n}, {s}): "
             + "; ".join(problems)
         )
     if bn is None:
@@ -316,11 +318,11 @@ def frontier_relax(dist, nbr, w, hi, *, mode: str = "auto", **tile_kw):
         return _ref.frontier_relax_ref(dist, nbr, w, hi)
     pad = -n % bn
     if pad:
-        dist = jnp.pad(dist, ((0, 0), (0, pad)), constant_values=jnp.inf)
+        dist = jnp.pad(dist, ((0, pad), (0, 0)), constant_values=jnp.inf)
         nbr = jnp.pad(nbr, ((0, pad), (0, 0)))
         w = jnp.pad(w, ((0, pad), (0, 0)), constant_values=jnp.inf)
     out = _fr_pallas(dist, nbr, w, hi, bn=bn, interpret=interpret)
-    return out[:, :n] if pad else out
+    return out[:n] if pad else out
 
 
 def floyd_warshall(d, *, mode: str = "auto"):

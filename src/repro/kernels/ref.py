@@ -131,19 +131,20 @@ def frontier_relax_ref(
 ) -> jax.Array:
     """Masked sparse frontier-relaxation oracle (one delta-stepping sweep).
 
-    O[q, j] = min(D[q, j], min_d mask(D[q, nbr[j, d]]) + w[j, d]) with
-    mask(x) = x where x < hi else +inf.  dist (s, n), nbr (n, deg) int32,
-    w (n, deg) -> (s, n); padded CSR lanes carry w = +inf so they never
-    win the min.
+    O[j, q] = min(D[j, q], min_d mask(D[nbr[j, d], q]) + w[j, d]) with
+    mask(x) = x where x < hi else +inf.  dist (n, s) nodes-major (the s
+    sources on the last axis), nbr (n, deg) int32, w (n, deg) -> (n, s);
+    padded CSR lanes carry w = +inf so they never win the min.
 
-    Replays the Pallas kernel's exact op order per element (gather ->
-    threshold mask -> broadcast-add -> min-reduce -> seed-min), so the
-    result is bit-identical to :func:`repro.kernels.frontier
-    .frontier_relax` for any node tiling: min is exact and the add is a
-    single rounding per term in both.  Computed in node chunks so the
-    (s, chunk, deg) gather intermediate stays bounded.
+    Replays the Pallas kernel's op sequence per element (gather ->
+    threshold mask -> broadcast-add -> min over the neighbour slots ->
+    seed-min), so the result is bit-identical to
+    :func:`repro.kernels.frontier.frontier_relax` for any node tiling:
+    min is exact and the add is a single rounding per term in both.
+    Computed in node chunks so the (chunk, deg, s) gather intermediate
+    stays bounded.
     """
-    s, n = dist.shape
+    n, s = dist.shape
     n2, deg = nbr.shape
     assert n == n2 and w.shape == nbr.shape, (dist.shape, nbr.shape, w.shape)
     hi = jnp.asarray(hi, dist.dtype)
@@ -152,8 +153,8 @@ def frontier_relax_ref(
     dist_p = dist
     if pad:
         # padded nodes: dist +inf, edges to node 0 with weight +inf — they
-        # relax to +inf and are sliced off, never touching real columns
-        dist_p = jnp.pad(dist, ((0, 0), (0, pad)), constant_values=jnp.inf)
+        # relax to +inf and are sliced off, never touching real rows
+        dist_p = jnp.pad(dist, ((0, pad), (0, 0)), constant_values=jnp.inf)
         nbr = jnp.pad(nbr, ((0, pad), (0, 0)))
         w = jnp.pad(w, ((0, pad), (0, 0)), constant_values=jnp.inf)
     steps = (n + pad) // chunk
@@ -161,16 +162,16 @@ def frontier_relax_ref(
     def body(c, out):
         ni = jax.lax.dynamic_slice(nbr, (c * chunk, 0), (chunk, deg))
         wi = jax.lax.dynamic_slice(w, (c * chunk, 0), (chunk, deg))
-        g = jnp.take(dist_p, ni.reshape(-1), axis=1).reshape(s, chunk, deg)
+        g = jnp.take(dist_p, ni, axis=0)                # (chunk, deg, s)
         g = jnp.where(g < hi, g, jnp.inf)
-        cand = jnp.min(g + wi[None, :, :], axis=2)      # (s, chunk)
-        cur = jax.lax.dynamic_slice(dist_p, (0, c * chunk), (s, chunk))
+        cand = jnp.min(g + wi[:, :, None], axis=1)      # (chunk, s)
+        cur = jax.lax.dynamic_slice(dist_p, (c * chunk, 0), (chunk, s))
         return jax.lax.dynamic_update_slice(
-            out, jnp.minimum(cur, cand), (0, c * chunk)
+            out, jnp.minimum(cur, cand), (c * chunk, 0)
         )
 
     out = jax.lax.fori_loop(0, steps, body, jnp.zeros_like(dist_p))
-    return out[:, :n] if pad else out
+    return out[:n] if pad else out
 
 
 def floyd_warshall_ref(d: jax.Array) -> jax.Array:

@@ -94,6 +94,26 @@ def test_env_override_reports_all_bad_knobs_at_once(monkeypatch):
     assert "bs=-1" in msg and "bn=0" in msg and "bucket='z'" in msg
 
 
+@pytest.mark.parametrize("m, bs, units", [(808, 128, 7), (40, 40, 1)])
+def test_frontier_batch_is_lane_width(monkeypatch, tmp_path, m, bs, units):
+    """Sources ride the lanes: the benchmark's sparse cell (n = 40960,
+    20 CSR lanes, 808 landmarks) solves 128-wide batches, 7 of them; a
+    landmark set under the lane width is one full-dim batch."""
+    from repro.core import sparse
+    from repro.kernels import measure
+
+    monkeypatch.delenv(autotune.ENV_FRONTIER_TILES, raising=False)
+    monkeypatch.delenv(autotune.ENV_FRONTIER_AUTOTUNE, raising=False)
+    monkeypatch.setenv(measure.ENV_MEASURE, "0")
+    monkeypatch.setenv(measure.ENV_TUNING_PATH, str(tmp_path / "t.json"))
+    autotune.clear_cache()
+    cfg = autotune.frontier_config(40960, 20, m)
+    assert cfg.bs == bs
+    assert sparse.sparse_units(m, cfg.bs) == units
+    assert autotune.frontier_cost(40960, 20, m, cfg).vmem_bytes <= (
+        autotune.vmem_budget())
+
+
 def test_env_autotune_disable(monkeypatch):
     monkeypatch.delenv(autotune.ENV_TILES, raising=False)
     monkeypatch.setenv(autotune.ENV_AUTOTUNE, "0")

@@ -289,8 +289,10 @@ def test_frontier_fit_samples_use_raw_sweep_time(monkeypatch):
     monkeypatch.setenv(measure.ENV_MEASURE, "refresh")
     autotune.clear_cache()
     measure.timer = tick
-    got = measure.calibrate_frontier(64, 4, 8, mode="ref")
+    # m past the lane width: the measured sweeps are lane-wide (n, 128)
+    got = measure.calibrate_frontier(256, 4, 160, mode="ref")
     assert got is not None and got.source == "measured"
+    assert got.config.bs == autotune.LANES
     rec = measure.load_store(cache=False)[
         "devices"][measure.device_kind()]
     assert rec["samples"], "no fit samples persisted"

@@ -33,7 +33,7 @@ from repro.core.sparse import (
 )
 from repro.core.streaming import LandmarkStreamingMapper
 from repro.data import euler_isometric_swiss_roll
-from repro.kernels import ops, ref
+from repro.kernels import autotune, ops, ref
 
 
 def _random_padded_csr(rng, n, deg, *, integer=False):
@@ -64,15 +64,17 @@ def _random_padded_csr(rng, n, deg, *, integer=False):
 # ------------------------------------------------- frontier kernel oracle --
 
 
+@pytest.mark.parametrize("s", [4, 128])
 @pytest.mark.parametrize("bn", [32, 40, 96])
-def test_frontier_relax_pallas_matches_ref(rng, bn):
-    """Pallas(interpret) vs the chunked CSR reference, bit-identical -
-    including inf (padded) lanes and a bn that does not divide n (the
-    padded-frontier masking path)."""
-    n, deg, s = 96, 5, 4
+def test_frontier_relax_pallas_matches_ref(rng, bn, s):
+    """Pallas(interpret) vs the chunked CSR reference, nodes-major (n, s),
+    bit-identical - including inf (padded) lanes, a batch under and at
+    the lane width, and a bn that does not divide n (the padded-frontier
+    masking path)."""
+    n, deg = 96, 5
     nbr, w, _ = _random_padded_csr(np.random.default_rng(3), n, deg)
-    dist = jnp.full((s, n), jnp.inf, jnp.float32)
-    dist = dist.at[jnp.arange(s), jnp.arange(s) * 7].set(0.0)
+    dist = jnp.full((n, s), jnp.inf, jnp.float32)
+    dist = dist.at[(jnp.arange(s) * 7) % n, jnp.arange(s)].set(0.0)
     for _ in range(2):  # a couple of sweeps so finite values spread
         dist = ops.frontier_relax(dist, nbr, w, jnp.inf, mode="ref")
     for hi in (np.inf, 4.0):
@@ -88,21 +90,28 @@ def test_frontier_relax_pallas_matches_ref(rng, bn):
         )
 
 
-def test_frontier_threshold_masks_exactly(rng):
-    """One masked sweep == the hand-written pull relaxation: tentative
-    distances at or above hi must not propagate, everything below must."""
-    n, deg, s = 24, 3, 2
+@pytest.mark.parametrize("s", [4, 128])
+def test_frontier_threshold_masks_exactly(rng, s):
+    """One masked sweep == the hand-written pull relaxation, nodes-major:
+    tentative distances at or above hi must not propagate, everything
+    below must - in the oracle and in the kernel on a bn that does not
+    divide n."""
+    n, deg = 24, 3
     nbr, w, _ = _random_padded_csr(np.random.default_rng(5), n, deg)
     dist = jnp.asarray(
-        np.where(rng.uniform(size=(s, n)) < 0.5, rng.uniform(0, 8, (s, n)),
+        np.where(rng.uniform(size=(n, s)) < 0.5, rng.uniform(0, 8, (n, s)),
                  np.inf).astype(np.float32)
     )
     hi = 3.0
     nbr_np, w_np, d_np = (np.asarray(a) for a in (nbr, w, dist))
-    g = d_np[:, nbr_np.reshape(-1)].reshape(s, n, deg)
+    g = d_np[nbr_np]                                    # (n, deg, s)
     g = np.where(g < hi, g, np.inf)
-    want = np.minimum(d_np, np.min(g + w_np[None], axis=2))
+    want = np.minimum(d_np, np.min(g + w_np[:, :, None], axis=1))
     got = np.asarray(ops.frontier_relax(dist, nbr, w, hi, mode="ref"))
+    np.testing.assert_array_equal(got, want)
+    got = np.asarray(
+        ops.frontier_relax(dist, nbr, w, hi, mode="pallas", bn=16)
+    )
     np.testing.assert_array_equal(got, want)
 
 
@@ -138,6 +147,33 @@ def test_sssp_panel_matches_dense_oracle_real_data():
     panel = np.asarray(sssp_panel(nbr, w, jnp.asarray(lm, jnp.int32)))
     dense = np.asarray(ref.floyd_warshall_ref(g))[lm]
     np.testing.assert_allclose(panel, dense, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("mode", ["ref", "pallas"])
+def test_sssp_panel_is_batch_invariant(mode):
+    """The settled panel is the minimum over paths of left-to-right
+    float32 path sums, whatever the batch: 16-wide and lane-wide (128)
+    source batches - different bucket bounds, rounds and a shifted-back
+    last batch - give array_equal panels on real (float) weights."""
+    from repro.core import knn
+
+    n, k, m = 256, 8, 144
+    x, _ = euler_isometric_swiss_roll(n, seed=4)
+    x = jnp.asarray(x)
+    d, i = knn.knn_blocked(x, k=k, block=128)
+    nbr, w = graph.knn_to_padded_csr(d, i, n=n)
+    lm = jnp.asarray(
+        hierarchical_landmarks(np.asarray(x), np.asarray(d), m=m), jnp.int32
+    )
+    panels = [
+        np.asarray(sssp_panel(
+            nbr, w, lm, mode=mode,
+            cfg=autotune.FrontierConfig(bs=bs, bn=n, bucket=bucket),
+        ))
+        for bs, bucket in ((16, 1), (128, 2))
+    ]
+    assert np.isfinite(panels[0]).all()
+    np.testing.assert_array_equal(panels[0], panels[1])
 
 
 def test_sssp_panel_disconnected_stays_inf():

@@ -130,12 +130,16 @@ def test_pairwise_sq_dists_compiles(n, one_chip, native):
                    _spec(s, (n, 3)))
 
 
-def test_frontier_relax_compiles(one_chip, native):
+@pytest.mark.parametrize("n, m", [(N_SPARSE, 1024), (40960, 808)])
+def test_frontier_relax_compiles(n, m, one_chip, native):
+    # 2k-wide padded CSR, default landmarks: the smoke's sparse phase and
+    # the benchmark's sparse cell; nodes-major (n, bs), sources on lanes
     s = one_chip
-    deg, m = 20, 1024      # 2k-wide padded CSR, default landmarks
-    bs = autotune.frontier_config(N_SPARSE, deg, m).bs
+    deg = 20
+    bs = autotune.frontier_config(n, deg, m).bs
+    assert bs == 128
     _assert_kernel(
         lambda d, nbr, w: ops.frontier_relax(d, nbr, w, 1.0),
-        _spec(s, (bs, N_SPARSE)), _spec(s, (N_SPARSE, deg), jnp.int32),
-        _spec(s, (N_SPARSE, deg)),
+        _spec(s, (n, bs)), _spec(s, (n, deg), jnp.int32),
+        _spec(s, (n, deg)),
     )
