@@ -22,10 +22,16 @@ the selection is bit-deterministic and backend-independent — the mesh
 path computes it from the same gathered host copy the dense regime's
 gate/border logic already uses, which is what makes checkpoints and the
 sparse-vs-dense agreement tests reproducible across backends.
+
+The host spans ``repro:landmarks:cover`` (steps 1-3) and
+``repro:landmarks:fill`` (step 4) name the selection's time in a
+profiler trace; neither waits on the device.
 """
 from __future__ import annotations
 
 import numpy as np
+
+from repro.core import telemetry
 
 
 def _fps(x: np.ndarray, m: int, start: int, cand=None) -> np.ndarray:
@@ -113,14 +119,16 @@ def hierarchical_landmarks(
     if coarse is None:
         coarse = int(round(np.sqrt(m)))
     coarse = max(1, min(coarse, m))
-    centers = _fps(x, coarse, start)
-    cell = _assign(x, x[centers])
-    # every center claims its own cell even under distance ties
-    cell[centers] = np.arange(coarse)
-    sizes = np.bincount(cell, minlength=coarse)
-    quota = _largest_remainder(sizes, m)
+    with telemetry.span("landmarks:cover"):
+        centers = _fps(x, coarse, start)
+        cell = _assign(x, x[centers])
+        # every center claims its own cell even under distance ties
+        cell[centers] = np.arange(coarse)
+        sizes = np.bincount(cell, minlength=coarse)
+        quota = _largest_remainder(sizes, m)
     picks = []
-    for c in range(coarse):
-        mask = cell == c
-        picks.append(_fps(x, int(quota[c]), int(centers[c]), cand=mask))
+    with telemetry.span("landmarks:fill"):
+        for c in range(coarse):
+            mask = cell == c
+            picks.append(_fps(x, int(quota[c]), int(centers[c]), cand=mask))
     return np.sort(np.unique(np.concatenate(picks)))

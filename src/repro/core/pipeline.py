@@ -311,6 +311,21 @@ def _make_gather_rows(mesh):
     )
 
 
+@functools.lru_cache(maxsize=None)
+def _make_replicate(mesh):
+    """Copy an array onto every device of the mesh, under the scope
+    ``sparse_embed/replicate`` (the all-gathers the partitioner inserts
+    carry it, so a device trace names the exchange); values unchanged."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    def replicate(a):
+        with jax.named_scope("sparse_embed"), jax.named_scope("replicate"):
+            return jax.lax.with_sharding_constraint(
+                a, NamedSharding(mesh, P()))
+
+    return jax.jit(replicate)
+
+
 class MeshBackend:
     """Mesh-sharded execution: same stage chain, explicit collectives.
 
@@ -570,13 +585,12 @@ class MeshBackend:
         return fn(nbr, w, lm_idx, panel, jnp.int32(lo), jnp.int32(hi), delta)
 
     def sparse_embed(self, cfg: PipelineConfig, panel, lm_idx):
-        from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.core import sparse as sparse_mod
 
         # one replicating gather of the (m, n) panel — within the
         # O(m n) residency bound; the MDS itself is O(m^2 + n m d)
         # replicated compute, same policy as the dense landmark tail
-        panel_rep = jax.device_put(panel, NamedSharding(self.mesh, P()))
+        panel_rep = _make_replicate(self.mesh)(panel)
         return sparse_mod.landmark_mds_general(
             panel_rep, lm_idx, d=cfg.d, max_iter=cfg.max_iter, tol=cfg.tol
         )

@@ -39,7 +39,8 @@ the *folded* (data, model) axis (every device, not every data row, owns
 m/p sources), the padded-CSR graph is replicated, and each device runs
 the identical solver on its rows with **zero collectives** in the hot
 loop.  The embed stage replicates the (m, n) panel once at the end —
-within the O(m * n) budget — and runs the general landmark MDS.
+within the O(m * n) budget, its all-gathers under the scope
+``sparse_embed/replicate`` — and runs the general landmark MDS.
 """
 from __future__ import annotations
 

@@ -549,3 +549,99 @@ def test_mesh_sparse_suite():
     )
     assert proc.returncode == 0, proc.stdout + "\n" + proc.stderr
     assert "ALL-MESH-SPARSE-OK" in proc.stdout
+
+
+_MESH_REFERENCE_SCRIPT = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import numpy as np, jax, jax.numpy as jnp
+import scipy.sparse, scipy.sparse.csgraph, scipy.spatial
+from jax.sharding import NamedSharding, PartitionSpec as P
+from repro.core.pipeline import ManifoldPipeline, MeshBackend, PipelineConfig
+from repro.core.sparse import sparse_isomap_stages
+from repro.data import euler_isometric_swiss_roll
+from repro.launch.mesh import make_mesh
+
+n, m = 1024, 64
+x, _ = euler_isometric_swiss_roll(n, seed=3)
+x = np.pad(x, ((0, 0), (0, 1))).astype(np.float32)  # 4 features: model axis
+cfg = PipelineConfig(k=10, d=2, block=128, regime="sparse", landmarks=m)
+extra = ("lm_idx", "panel", "embedding", "csr_nbr", "csr_w")
+
+mesh = make_mesh((2, 2), ("data", "model"))
+art = ManifoldPipeline(
+    sparse_isomap_stages(m), cfg=cfg, backend=MeshBackend(mesh),
+    exports=extra,
+).run(jax.device_put(jnp.asarray(x), NamedSharding(mesh, P("data", "model"))))
+local = ManifoldPipeline(sparse_isomap_stages(m), cfg=cfg, exports=extra).run(
+    jnp.asarray(x))
+
+# the scoped replication before landmark MDS moves no bit, and every
+# all-gather it compiles to carries the scope a device trace reads
+np.testing.assert_array_equal(
+    np.asarray(art["embedding"]), np.asarray(local["embedding"]))
+from repro.core.pipeline import _make_replicate
+tile = NamedSharding(mesh, P("data", "model"))
+shape = jax.ShapeDtypeStruct((m, n), jnp.float32, sharding=tile)
+text = _make_replicate(mesh).lower(shape).compile().as_text()
+gathers = [l for l in text.splitlines() if " all-gather(" in l]
+assert gathers and all("/sparse_embed/replicate/" in l for l in gathers), text
+print("OK replicate-bitmatch-and-scope")
+
+# panel rows against float64 Dijkstra over the program's own padded CSR
+# (edge nbr[j, d] -> j of length w[j, d]; pad lanes are +inf self-loops)
+nbr, w = np.asarray(art["csr_nbr"]), np.asarray(art["csr_w"], np.float64)
+lm = np.asarray(art["lm_idx"])
+assert lm.shape == (m,) and m % 4 == 0
+live = np.isfinite(w)
+rows = np.repeat(np.arange(n), nbr.shape[1])[live.ravel()]
+g = scipy.sparse.csr_matrix((w[live], (nbr[live], rows)), shape=(n, n))
+want = scipy.sparse.csgraph.dijkstra(g, directed=True, indices=lm)
+got = np.asarray(art["panel"], np.float64)
+assert np.isfinite(want).all(), "the test graph must be connected"
+assert np.array_equal(np.isfinite(got), np.isfinite(want))
+pos = want > 0
+rel = np.max(np.abs(got[pos] - want[pos]) / want[pos])
+assert np.all(got[~pos] == 0.0)
+# float32 path sums of up to about 50 hops: each add rounds by at most
+# 2**-24 of the sum, so 50 * 6e-8 = 3e-6 bounds the relative gap
+assert rel < 1e-5, rel
+print("OK panel-vs-dijkstra", rel)
+
+# the embedding against a float64 landmark MDS of those same rows
+d2 = np.square(want)
+sub = d2[:, lm]
+h = np.eye(m) - 1.0 / m
+b = -0.5 * h @ sub @ h
+lam, vec = np.linalg.eigh(0.5 * (b + b.T))
+lam, vec = lam[::-1][:2], vec[:, ::-1][:, :2]
+chart = -0.5 * (d2 - sub.mean(axis=1)[:, None]).T @ (vec / np.sqrt(lam))
+emb = np.asarray(art["embedding"], np.float64)
+disp = scipy.spatial.procrustes(chart, emb)[2]
+# the disparity is a sum of squared relative errors: float32 rows and a
+# float32 power iteration (tol 1e-9) against float64 eigh err by about
+# 1e-7 a coordinate, so about 1e-13 (4e-14 read here); bfloat16 (4e-3 a
+# coordinate) would read about 1e-5.  (On the chip, against a chart of
+# 32 other sources at full size, emb_gap reads about 3e-5.)
+assert disp < 1e-10, disp
+print("OK embedding-vs-float64-lmds", disp)
+print("ALL-MESH-REFERENCE-OK")
+"""
+
+
+@pytest.mark.slow
+def test_mesh_sparse_panel_and_embedding_match_float64_references():
+    """On a (2, 2) mesh of four host devices at n = 1024 (m = 64, 16
+    landmark rows a device): the panel rows equal float64 Dijkstra over
+    the same padded CSR, the embedding lies within Procrustes tolerance
+    of a float64 landmark MDS of those rows, and the scoped replication
+    leaves the embedding bit-equal to one device's."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    env.pop("XLA_FLAGS", None)
+    proc = subprocess.run(
+        [sys.executable, "-c", _MESH_REFERENCE_SCRIPT],
+        capture_output=True, text=True, env=env, timeout=900,
+    )
+    assert proc.returncode == 0, proc.stdout + "\n" + proc.stderr
+    assert "ALL-MESH-REFERENCE-OK" in proc.stdout

@@ -150,6 +150,17 @@ def test_pipeline_run_spans_stages_and_never_blocks(monkeypatch, opened):
     assert np.isfinite(np.asarray(art["embedding"])).all()
 
 
+def test_landmark_selection_is_spanned(opened):
+    """The host selection opens its two phase spans, in order."""
+    from repro.core.landmarks import hierarchical_landmarks
+
+    x, _ = euler_isometric_swiss_roll(512, seed=5)
+    d, _ = knn.knn_blocked(jnp.asarray(x, jnp.float32), k=6, block=128)
+    lm = hierarchical_landmarks(x, np.asarray(d), m=40)
+    assert lm.shape == (40,)
+    assert [n for n, _ in opened] == ["landmarks:cover", "landmarks:fill"]
+
+
 def test_pipeline_checkpoint_saves_are_spanned(tmp_path, opened):
     from repro.checkpoint import CheckpointManager
 
