@@ -17,16 +17,19 @@ candidate's cost is::
 
     time = max(compute, memory)
     compute = 2*m*n*k / (vpu_ops * lane_fill * sublane_fill)
+              * bundles_per_vreg_term / 0.5
     memory  = HBM bytes(tiling) / hbm_bw
 
 where ``lane_fill``/``sublane_fill`` penalize tiles under the (8, 128)
-VPU register shape and HBM bytes count the seed read + output write + the
-per-grid-pass contraction re-reads (``a`` is re-read n/bn times, ``b``
-m/bm times).  Candidates whose double-buffered VMEM working set exceeds
-the budget are discarded.  ``unroll`` is not swept: the kernel does one
-add and one min per contraction term whatever its grouping, so the model
-has nothing to rank it by, and every candidate carries the default
-grouping (:func:`grouping`).
+VPU register shape, ``bundles_per_vreg_term`` is the kernel's static
+schedule at the block's register sub-tile over its VALU floor of 0.5
+(:data:`repro.kernels.minplus.BUNDLES_PER_VREG_TERM`: a narrow block is
+bound by lane broadcasts, not by the VALU), and HBM bytes count the seed
+read + output write + the per-grid-pass contraction re-reads (``a`` is
+re-read n/bn times, ``b`` m/bm times).  Candidates whose double-buffered
+VMEM working set exceeds the budget are discarded.  ``unroll`` is not
+swept: it is inert in the kernel, and every candidate carries the
+default grouping (:func:`grouping`).
 
 A dim longer than :data:`WHOLE_DIM_MAX` is tiled only in multiples of the
 chip's (8, 128) register tiling; :func:`padded_shape` is the problem
@@ -58,8 +61,13 @@ model — see that module for the store format and semantics.
 from __future__ import annotations
 
 import functools
+import math
 import os
 from typing import Iterator, NamedTuple
+
+from repro.kernels.minplus import (
+    BUNDLES_PER_VREG_TERM, MAX_RN, row_scratch, subtile,
+)
 
 # ----------------------------------------------------------- machine model --
 
@@ -191,16 +199,17 @@ def modeled_cost(
     if op not in FUSED_OPS and op not in _UNSEEDED:
         raise ValueError(f"unknown op {op!r}; expected one of "
                          f"{FUSED_OPS + _UNSEEDED}")
-    bm, bn, bk, unroll = clamp(cfg, m, n, k)
+    bm, bn, bk, _ = clamp(cfg, m, n, k)
     seeded = op in FUSED_OPS
 
     hw = chip()
     # compute: 2 VPU ops (add + min) per (i, j, k) triple, derated by
-    # register fill
+    # register fill and by the sub-tile's schedule over the VALU floor
     lane_fill = min(bn, 128) / 128.0
     sublane_fill = min(bm, 8) / 8.0
     eff_ops = hw.vpu_ops * lane_fill * sublane_fill
-    compute_s = (2.0 * m * n * k) / eff_ops
+    compute_s = (2.0 * m * n * k) / eff_ops * (
+        bundles_per_vreg_term(bm, bn) / 0.5)
 
     # memory: contraction operands are re-fetched once per orthogonal
     # grid pass; seed read + output write land once per output tile
@@ -213,12 +222,11 @@ def modeled_cost(
     hbm_s = hbm_bytes / (hbm_bw if hbm_bw else hw.hbm_bw)
 
     # VMEM working set: a + b tiles (double-buffered while streaming),
-    # accumulator + output tile (+ seed tile view), and the (bm, bn)
-    # broadcast terms of one unroll group
+    # output tile (+ seed tile), and the kernel's row copy of b
     vmem = itemsize * (
         2 * (bm * bk + bk * bn)
         + (3 if seeded else 2) * bm * bn
-        + unroll * bm * bn
+        + sum(math.prod(r.shape) for r in row_scratch(bk, bn, "float32"))
     )
     return Cost(
         time_s=max(compute_s, hbm_s) + launch_s,
@@ -227,6 +235,15 @@ def modeled_cost(
         hbm_bytes=float(hbm_bytes),
         vmem_bytes=vmem,
     )
+
+
+def bundles_per_vreg_term(bm: int, bn: int) -> float:
+    """Bundles per vreg-term of the kernel's schedule at a (bm, bn)
+    block: the entry of its register sub-tile's width in vregs
+    (:func:`repro.kernels.minplus.subtile`), or of the widest tabled
+    width below it."""
+    v = -(-subtile(bm, bn)[1] // 128)
+    return BUNDLES_PER_VREG_TERM[max(w for w in BUNDLES_PER_VREG_TERM if w <= v)]
 
 
 #: longest dim a block may span whole; a longer dim is tiled in multiples
@@ -258,16 +275,17 @@ def _tile_sizes(dim: int, *, cap: int = 512, align: int = 8) -> list[int]:
     of its (8, 128) register tiling or the whole array dim: ``align`` is
     8 for a sublane dim, 128 for a lane dim.  Every :func:`padded_dim`
     gets at least one size."""
-    sizes = [t for t in (8, 16, 32, 64, 128, 256, 512)
+    sizes = [t for t in (8, 16, 32, 64, 128, 256, 512, 1024)
              if t % align == 0 and t <= min(dim, cap) and dim % t == 0]
     if dim <= WHOLE_DIM_MAX:
         sizes.append(dim)
     return sorted(set(sizes))
 
 
-#: largest row tile: the kernels' statically unrolled contraction keeps
-#: per-column broadcasts of the row tile live, and at 512 rows the chip's
-#: compiler runs out of scoped VMEM for some (bn, bk)
+#: largest row tile.  The kernel's register sub-tile keeps its schedule
+#: the same whatever the rows (512 rows compile for a v5e), so taller
+#: blocks only cut the re-reads of ``b``, which the compute-bound kernel
+#: does not wait for, at twice the seed and output tiles' VMEM
 MAX_BM = 256
 
 
@@ -284,12 +302,13 @@ def candidates(m: int, n: int, k: int) -> Iterator[TileConfig]:
     """Enumerate tile configs for the :func:`padded_shape` of an
     (m, n, k) problem: power-of-two tiles dividing each padded dim that
     the chip's compiler accepts (``bn`` and ``bk`` are lane dims of some
-    block), each with the default :func:`grouping`.  The (clamped)
-    static default is included when it is such a tiling."""
+    block; ``bn`` up to the widest register sub-tile), each with the
+    default :func:`grouping`.  The (clamped) static default is included
+    when it is such a tiling."""
     m, n, k = padded_shape(m, n, k)
     seen = set()
     for bm in _tile_sizes(m, cap=MAX_BM):
-        for bn in _tile_sizes(n, align=128):
+        for bn in _tile_sizes(n, cap=MAX_RN, align=128):
             for bk in _tile_sizes(k, align=128):
                 cfg = TileConfig(bm, bn, bk, grouping(bk))
                 if cfg not in seen:

@@ -10,8 +10,8 @@ Composed from the plain :mod:`repro.kernels.minplus` kernel this
 materializes the full (m, n) min-plus product in HBM before the
 elementwise min.  The fused form is the same seeded accumulation the
 Phase-2/Phase-3 kernels use - the output tile is seeded from E's tile at
-contraction step 0 and the rank-``unroll`` updates accumulate into it in
-VMEM - so the border IS :mod:`repro.kernels.minplus_update` with the
+contraction step 0 and the rank-1 updates accumulate into it in
+registers - so the border IS :mod:`repro.kernels.minplus_update` with the
 edge panel bound as both seed and first contraction operand:
 
   minplus_border(e, a) == minplus_update(e, e, a)

@@ -8,8 +8,9 @@ output tile is seeded from G's tile at contraction step 0 and the rank-b
 updates accumulate into it in VMEM, so the intermediate never exists.
 
 Per-step VMEM footprint is bm*bk + bk*bn + 2*bm*bn floats (the G tile
-rides in with the output tile), double-buffered for the streamed inputs:
-about 2 MiB at the default 256-tiles, inside the 16 MiB scoped-VMEM
+rides in with the output tile), double-buffered for the streamed inputs,
+plus the bk*bn row copy of R: about 5 MiB at the (256, 1024, 128) tiles
+the autotuner picks for the APSP update, inside the 16 MiB scoped-VMEM
 default of a v5e kernel.  HBM traffic drops from 3 n^2 + 2 n b to 2 n^2 + 2 n b
 floats per diagonal iteration.
 """
@@ -20,17 +21,20 @@ import functools
 import jax
 from jax.experimental import pallas as pl
 
-from repro.kernels.minplus import COMPILER_PARAMS, minplus_accumulate
+from repro.kernels.minplus import (
+    COMPILER_PARAMS, minplus_accumulate, row_scratch,
+)
 
 
-def _minplus_update_kernel(g_ref, c_ref, r_ref, o_ref, *, unroll: int):
+def _minplus_update_kernel(g_ref, c_ref, r_ref, o_ref, *scratch,
+                           interpret: bool):
     # Same min-plus accumulation as the plain kernel; only the accumulator
     # seed differs (G's tile instead of +inf).
     @pl.when(pl.program_id(2) == 0)
     def _init():
         o_ref[...] = g_ref[...]
 
-    o_ref[...] = minplus_accumulate(o_ref[...], c_ref, r_ref, unroll=unroll)
+    minplus_accumulate(o_ref, c_ref, r_ref, *scratch, interpret=interpret)
 
 
 @functools.partial(
@@ -49,7 +53,8 @@ def minplus_update(
 ) -> jax.Array:
     """O[i,j] = min(G[i,j], min_k C[i,k] + R[k,j]).
 
-    Shapes: g (m, n), c (m, k), r (k, n) -> (m, n).
+    Shapes: g (m, n), c (m, k), r (k, n) -> (m, n).  ``unroll`` is inert,
+    as in :func:`repro.kernels.minplus.minplus`.
     """
     m, n = g.shape
     m2, k = c.shape
@@ -64,7 +69,7 @@ def minplus_update(
     assert bk % unroll == 0
 
     grid = (m // bm, n // bn, k // bk)
-    kernel = functools.partial(_minplus_update_kernel, unroll=unroll)
+    kernel = functools.partial(_minplus_update_kernel, interpret=interpret)
     return pl.pallas_call(
         kernel,
         grid=grid,
@@ -75,6 +80,7 @@ def minplus_update(
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), g.dtype),
+        scratch_shapes=row_scratch(bk, bn, r.dtype),
         compiler_params=COMPILER_PARAMS,
         interpret=interpret,
     )(g, c, r)

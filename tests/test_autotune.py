@@ -17,6 +17,21 @@ def test_best_config_is_valid_and_beats_default():
             assert cost.time_s <= dcost.time_s * (1.0 + 1e-9)
 
 
+@pytest.mark.parametrize("n", [24576, 18432])
+def test_dense_cell_updates_get_the_widest_sub_tile(n):
+    """The benchmark's dense updates, one chip's n = 24576 and a mesh
+    chip's n = 18432 quarter, rank 128: blocks 1024 lanes wide, whose
+    register sub-tiles the kernel schedules fastest."""
+    from repro.kernels.minplus import BUNDLES_PER_VREG_TERM, subtile
+
+    cfg, cost = autotune.best_config("minplus_update", n, n, 128)
+    assert (cfg.bm, cfg.bn, cfg.bk) == (256, 1024, 128)
+    assert subtile(cfg.bm, cfg.bn) == (8, 1024)
+    assert autotune.bundles_per_vreg_term(cfg.bm, cfg.bn) == min(
+        BUNDLES_PER_VREG_TERM.values())
+    assert cost.vmem_bytes <= autotune.vmem_budget()
+
+
 def test_odd_shapes_get_a_config():
     # shapes with no power-of-two divisor still resolve (whole-dim tile)
     cfg, _ = autotune.best_config("minplus_update", 20, 20, 20)

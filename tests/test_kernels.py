@@ -25,6 +25,12 @@ from repro.kernels.pairwise_dist import pairwise_sq_dists as pd_pallas
         (128, 64, 128, 64, 64, 32, 8),
         (256, 256, 256, 128, 128, 128, 16),
         (8, 8, 8, 8, 8, 8, 1),
+        # register sub-tiles: one 8-row group over a 1024-lane block; 24
+        # rows (three groups) over 384 lanes and a 96-deep contraction;
+        # 24 rows over one 128-lane strip, two contraction steps
+        (16, 128, 1024, 8, 1024, 128, 8),
+        (48, 96, 384, 24, 384, 96, 8),
+        (24, 256, 256, 24, 128, 128, 8),
     ],
 )
 def test_minplus_matches_ref(m, k, n, bm, bn, bk, unroll, rng):
@@ -34,6 +40,7 @@ def test_minplus_matches_ref(m, k, n, bm, bn, bk, unroll, rng):
     got = mp_pallas(a, b, bm=bm, bn=bn, bk=bk, unroll=unroll, interpret=True)
     np.testing.assert_allclose(got, want, rtol=1e-6)
     np.testing.assert_allclose(ref.minplus_ref(a, b), want, rtol=1e-6)
+    assert np.array_equal(np.asarray(got), np.asarray(ref.minplus_ref(a, b)))
 
 
 def test_minplus_with_inf(rng):
@@ -59,6 +66,8 @@ def _closed_diag_block(rng, b):
         (64, 192, 32, 64, 32, 8),
         (128, 128, 64, 128, 128, 16),
         (8, 8, 8, 8, 8, 1),
+        (24, 1024, 24, 1024, 24, 8),
+        (128, 384, 8, 384, 128, 8),
     ],
 )
 def test_minplus_panel_row_matches_ref(b, n, bm, bn, bk, unroll, rng):
@@ -80,6 +89,8 @@ def test_minplus_panel_row_matches_ref(b, n, bm, bn, bk, unroll, rng):
         (192, 64, 64, 32, 64, 8),
         (128, 128, 128, 64, 32, 2),
         (8, 8, 8, 8, 8, 1),
+        (48, 128, 24, 128, 128, 8),
+        (16, 96, 8, 96, 96, 8),
     ],
 )
 def test_minplus_panel_col_matches_ref(m, b, bm, bn, bk, unroll, rng):
@@ -110,6 +121,8 @@ def test_minplus_panel_with_inf(rng):
         (16, 128, 8, 64, 32, 8),
         (64, 64, 64, 64, 64, 16),
         (8, 8, 8, 8, 8, 1),
+        (8, 1024, 8, 1024, 128, 8),
+        (24, 384, 24, 384, 128, 8),
     ],
 )
 def test_minplus_border_matches_ref(m, n, bm, bn, bk, unroll, rng):

@@ -46,18 +46,24 @@ def test_minplus_update_ref_bit_identical_to_unfused(m, k, n, rng):
         (64, 64, 64, 32, 32, 32, 8),
         (128, 64, 96, 64, 32, 64, 8),
         (8, 8, 8, 8, 8, 8, 1),
+        # register sub-tiles at their edges (kernels/minplus.subtile)
+        (16, 128, 2048, 8, 1024, 128, 8),
+        (48, 64, 384, 24, 384, 64, 8),
+        (24, 256, 256, 24, 128, 128, 8),
     ],
 )
 def test_minplus_update_pallas_matches_oracle(m, k, n, bm, bn, bk, unroll, rng):
     g = rng.uniform(0, 30, (m, n)).astype(np.float32)
     c = rng.uniform(0, 10, (m, k)).astype(np.float32)
     r = rng.uniform(0, 10, (k, n)).astype(np.float32)
+    c[c < 1.0] = np.inf  # no-edge entries ride through every sub-tile
     want = np.minimum(g, np.min(c[:, :, None] + r[None, :, :], axis=1))
     got = mpu_pallas(
         g, c, r, bm=bm, bn=bn, bk=bk, unroll=unroll, interpret=True
     )
     np.testing.assert_allclose(got, want, rtol=1e-6)
     np.testing.assert_array_equal(ref.minplus_update_ref(g, c, r), want)
+    np.testing.assert_array_equal(got, ref.minplus_update_ref(g, c, r))
 
 
 def test_apsp_fused_geodesics_bit_identical_to_unfused(rng):
