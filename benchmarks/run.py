@@ -5,7 +5,7 @@ Prints ``name,us_per_call,derived`` CSV rows:
   * scaling_*      - paper Tables I-III analogue: per-stage wall time of the
                      end-to-end Isomap pipeline vs problem size n (CPU
                      measurements; the device-count dimension of the paper's
-                     tables is covered by the dry-run roofline model).
+                     tables is the chip benchmark's four-chip cells, bench/).
   * blocksize_*    - paper Fig. 6 analogue: end-to-end time vs block size b.
   * kernel_*       - min-plus / FW / pairwise kernel microbenchmarks
                      (interpret-mode Pallas is not representative on CPU, so
@@ -779,25 +779,6 @@ def bench_pipeline(checkpoint_secs: float | None = None):
         )
 
 
-def bench_lm_smoke():
-    """One smoke train-step timing per architecture family."""
-    from repro.configs import get_smoke_config
-    from repro.models.model import build_model
-    from repro.sharding import materialize
-
-    for arch in ("llama3-8b", "granite-moe-1b-a400m", "jamba-v0.1-52b",
-                 "xlstm-350m"):
-        cfg = get_smoke_config(arch)
-        model = build_model(cfg)
-        params = materialize(model.param_specs(), jax.random.PRNGKey(0))
-        batch = {"tokens": jnp.ones((2, 33), jnp.int32)}
-        if cfg.kind == "encdec":
-            batch["frames"] = jnp.ones((2, cfg.enc_seq, cfg.d_model), jnp.bfloat16)
-        fn = jax.jit(lambda p, b: model.loss(p, b)[0])
-        t = _timeit(fn, params, batch, repeats=2)
-        _row(f"lm_smoke_loss_{arch}", t, "")
-
-
 def bench_embedding(smoke=False):
     """Embedding-objective comparison: wall clock + residual variance of
     the spectral / stress / path tails on one dense fit (the headline
@@ -846,7 +827,6 @@ _BENCHES = {
     "spectral": bench_spectral,
     "pipeline": bench_pipeline,
     "embedding": bench_embedding,
-    "lm": bench_lm_smoke,
 }
 
 

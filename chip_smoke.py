@@ -334,13 +334,6 @@ def main(argv=None) -> int:
     except SmokeFailure as e:
         print(f"chip_smoke: FAILED: {e}", file=sys.stderr)
         return 1
-    host_flag_setters = [m for m in ("repro.launch.dryrun",
-                                     "benchmarks.perf_iterations")
-                         if m in sys.modules]
-    if host_flag_setters:
-        print(f"chip_smoke: FAILED: imported {host_flag_setters}, which set "
-              "XLA_FLAGS for host devices", file=sys.stderr)
-        return 1
     log("total", wall_s=time.time() - t0)
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,

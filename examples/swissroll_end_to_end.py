@@ -8,6 +8,7 @@ centering, and the distributed simultaneous power iteration.
     python examples/swissroll_end_to_end.py          # 8 simulated devices
 """
 import os
+import tempfile
 
 os.environ.setdefault(
     "XLA_FLAGS", "--xla_force_host_platform_device_count=8"
@@ -36,7 +37,7 @@ def main():
 
     # fault tolerance: APSP checkpoints every 4 diagonal panels (the
     # paper's every-10-iterations RDD checkpoint, as a restart unit)
-    mgr = CheckpointManager("/tmp/isomap_ckpt")
+    mgr = CheckpointManager(tempfile.mkdtemp(prefix="isomap_ckpt-"))
     saved = []
 
     def ckpt_cb(g, next_iter):

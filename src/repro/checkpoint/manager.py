@@ -1,7 +1,8 @@
 """Sharded, async, elastic checkpointing.
 
 Design points for 1000+ node scale (the restart path is the fault-
-tolerance unit for both the LM trainer and the APSP panel loop):
+tolerance unit of the pipeline's stage boundaries and the APSP panel
+loop):
 
 * **Logical-array checkpoints**: leaves are saved as full logical arrays
   (device shards gathered per host), with the pytree structure flattened

@@ -119,8 +119,8 @@ def _apsp_shard_body(
     rank of a row/column group in the baseline (the faithful port of the
     paper's one-block-one-task mapping).  When set, each rank computes a
     1/p slice of the panel and the group all-gathers the result - panel
-    FLOPs drop p-fold for one extra (b x n/p) gather per iteration (see
-    EXPERIMENTS.md SPerf, apsp iteration 1).  Callers leaving it unset
+    FLOPs drop p-fold for one extra (b x n/p) gather per iteration.
+    Callers leaving it unset
     get the roofline decision (:func:`repro.kernels.ops.auto_split_panels`).
     """
     di = folded_axis_index(data_axis)

@@ -150,11 +150,11 @@ def knn_ring(
     enumeration); row counts that do not divide the mesh are padded with
     masked sentinel rows and the pad is stripped from the result.  The
     feature dimension is sharded over `feat_axis`; with
-    ``gather_features`` (default, see EXPERIMENTS.md SPerf cell D) each
-    device all-gathers its slab's features once up front (O(local x D)
-    moved) and every ring step is one fused :func:`repro.kernels.ops
-    .knn_topk` launch seeded with the previous step's candidate lists -
-    the (local, local) distance block lives only in VMEM; otherwise the
+    ``gather_features`` (default) each device all-gathers its slab's
+    features once up front (O(local x D) moved) and every ring step is
+    one fused :func:`repro.kernels.ops.knn_topk` launch seeded with the
+    previous step's candidate lists - the (local, local) distance block
+    lives only in VMEM; otherwise the
     additive decomposition of ||x-y||^2 is psum-reduced per ring step
     (O(local^2) per step - the faithful-but-naive baseline, which does
     materialize the block).  `split_axis` (e.g. the "pod" axis) splits

@@ -3,4 +3,3 @@ from repro.data.manifolds import (  # noqa: F401
     swiss_roll_classic,
     synthetic_emnist,
 )
-from repro.data.tokens import TokenPipeline, synthetic_token_batches  # noqa: F401

@@ -10,8 +10,7 @@ small panels leave the grid degenerate and big tiles can blow the VMEM
 working set.
 
 This module picks the tiles **at trace time** from an analytic roofline
-model - the same machine model :mod:`repro.launch.analytics` scores whole
-pipeline stages with (:data:`CHIPS` is its single source of truth).
+model (:data:`CHIPS` holds its machine constants).
 Min-plus runs on the VPU (the MXU systolic array only does *,+), so a
 candidate's cost is::
 

@@ -8,7 +8,7 @@ execute in interpret mode or fall back to the references, selectable via
 ``mode``:
 
   - "auto":     pallas on TPU, reference elsewhere (default; used by the
-                distributed paths so dry-run lowering stays pure-XLA)
+                distributed paths, so off-TPU lowering stays pure XLA)
   - "pallas":   force the Pallas kernel (interpret=True off-TPU) - used by
                 the kernel test suite
   - "ref":      force the jnp oracle

@@ -1,11 +1,7 @@
-"""Serving launcher: LM generation loop + manifold streaming service.
-
-``python -m repro.launch.serve --arch smollm-135m --smoke`` runs a real
-batched generation on CPU; the same prefill/decode step functions are what
-the dry-run lowers for the prefill_32k / decode_32k / long_500k shapes.
+"""Serving launcher: the manifold streaming service.
 
 ``python -m repro.launch.serve --manifold swissroll`` drives the staged
-ManifoldPipeline instead: fit exact Isomap on a base batch (stage-boundary
+ManifoldPipeline: fit exact Isomap on a base batch (stage-boundary
 checkpointed), then serve streamed arrivals as a request/response service -
 per-point requests flow through the BatchedMapperService arrival queue
 (max-batch-size / max-batch-latency scheduling) into the StreamingMapper,
@@ -14,87 +10,14 @@ and the driver reports p50/p99 request latency alongside throughput.
 from __future__ import annotations
 
 import argparse
-import functools
 import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import configs
 from repro.launch import mesh as mesh_lib
 from repro.launch.compile_cache import enable_compile_cache
-from repro.models.model import build_model
-from repro.sharding import LogicalRules, materialize, spec_shardings
-
-
-def generate(
-    arch: str,
-    *,
-    batch: int = 4,
-    prompt_len: int = 16,
-    gen_len: int = 16,
-    smoke: bool = True,
-    mesh=None,
-    temperature: float = 0.0,
-    seed: int = 0,
-):
-    cfg = configs.get_smoke_config(arch) if smoke else configs.get_config(arch)
-    mesh = mesh or mesh_lib.make_mesh((1, 1), ("data", "model"))
-    rules = LogicalRules(mesh)
-    model = build_model(cfg)
-    p_specs = model.param_specs()
-
-    rng = np.random.default_rng(seed)
-    prompts = rng.integers(1, cfg.vocab, (batch, prompt_len), dtype=np.int32)
-    feed = {"tokens": jnp.asarray(prompts)}
-    if cfg.kind == "encdec":
-        feed["frames"] = jnp.asarray(
-            rng.normal(size=(batch, cfg.enc_seq, cfg.d_model)), jnp.bfloat16
-        )
-    if cfg.vision_tokens:
-        feed["patches"] = jnp.asarray(
-            rng.normal(size=(batch, cfg.vision_tokens, cfg.d_model)),
-            jnp.bfloat16,
-        )
-
-    with mesh:
-        params = materialize(p_specs, jax.random.PRNGKey(0), rules)
-        prefill = jax.jit(
-            functools.partial(model.prefill, pad_to=prompt_len + gen_len)
-        )
-        decode = jax.jit(model.decode_step)
-
-        t0 = time.time()
-        logits, cache = prefill(params, feed)
-        out_tokens = []
-        key = jax.random.PRNGKey(seed)
-        kv_len = jnp.full((batch,), prompt_len + (cfg.vision_tokens or 0),
-                          jnp.int32)
-        tok = _sample(logits[:, -1], key, temperature)
-        out_tokens.append(np.asarray(tok))
-        t_prefill = time.time() - t0
-
-        t0 = time.time()
-        for i in range(gen_len - 1):
-            key, sub = jax.random.split(key)
-            logits, cache = decode(
-                params, {"token": tok[:, None], "kv_len": kv_len, "cache": cache}
-            )
-            kv_len = kv_len + 1
-            tok = _sample(logits[:, -1], sub, temperature)
-            out_tokens.append(np.asarray(tok))
-        jax.block_until_ready(tok)
-        t_decode = time.time() - t0
-
-    gen = np.stack(out_tokens, axis=1)
-    return {
-        "prompts": prompts,
-        "generated": gen,
-        "prefill_s": t_prefill,
-        "decode_s": t_decode,
-        "tok_per_s": batch * (gen_len - 1) / max(t_decode, 1e-9),
-    }
 
 
 # Fixed feature-padding width for sharded manifold serving: checkpoints
@@ -392,27 +315,16 @@ def serve_manifold(
     }
 
 
-def _sample(logits, key, temperature):
-    if temperature <= 0:
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    return jax.random.categorical(key, logits / temperature).astype(jnp.int32)
-
-
 def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", choices=configs.ARCHS)
-    ap.add_argument("--batch", type=int, default=4)
-    ap.add_argument("--prompt-len", type=int, default=16)
-    ap.add_argument("--gen-len", type=int, default=16)
-    # BooleanOptionalAction: --smoke / --no-smoke (store_true with
-    # default=True made the full configs unreachable from the CLI)
+    ap.add_argument(
+        "--manifold", choices=("swissroll",), required=True,
+        help="the dataset whose manifold is fitted and served",
+    )
+    # accepted so the CI smoke commands parse; sizes come from --n-base
+    # and --n-stream
     ap.add_argument(
         "--smoke", action=argparse.BooleanOptionalAction, default=True
-    )
-    ap.add_argument("--temperature", type=float, default=0.0)
-    ap.add_argument(
-        "--manifold", choices=("swissroll",),
-        help="serve the manifold pipeline instead of an LM arch",
     )
     ap.add_argument("--n-base", type=int, default=512)
     ap.add_argument("--n-stream", type=int, default=256)
@@ -494,74 +406,57 @@ def main():
     ap = build_parser()
     args = ap.parse_args()
     enable_compile_cache()
-    if args.manifold:
-        mesh_shape = None
-        if args.mesh:
-            parts = args.mesh.lower().split("x")
-            if len(parts) != 2 or not all(p.isdigit() and p for p in parts):
-                ap.error("--mesh must look like 4x2 (data x model)")
-            mesh_shape = (int(parts[0]), int(parts[1]))
-        out = serve_manifold(
-            n_base=args.n_base,
-            n_stream=args.n_stream,
-            stream_batch=args.stream_batch,
-            max_latency_ms=args.max_latency_ms,
-            arrival=args.arrival,
-            k=args.k,
-            d=args.d,
-            block=args.block,
-            seed=args.seed,
-            checkpoint_dir=args.checkpoint_dir,
-            resume=args.resume,
-            checkpoint_secs=args.checkpoint_secs,
-            absorb=args.absorb,
-            mesh_shape=mesh_shape,
-            regime=args.regime,
-            landmarks=args.landmarks,
-            objective=args.objective,
-            replicas=args.replicas,
-            router_vnodes=args.router,
-            pipeline_depth=args.pipeline_depth,
-        )
-        print(
-            f"[serve manifold] regime={out['regime']} "
-            f"objective={out['objective']} "
-            f"fit={out['fit_s']:.2f}s "
-            f"serve={out['serve_s']:.3f}s "
-            f"({out['points_per_s']:.0f} pts/s) "
-            f"p50={out['latency_p50_ms']:.1f}ms "
-            f"p99={out['latency_p99_ms']:.1f}ms "
-            f"wait={out['queue_wait_ms']:.1f}ms "
-            f"service={out['service_ms']:.1f}ms "
-            f"mean_batch={out['mean_batch']:.1f} "
-            f"absorbed={out['absorbed']} v{out['serving_version']} "
-            f"err={out['procrustes_error']:.2e} "
-            f"rv={out['residual_variance']:.3f}"
-        )
-        for s in out["replica_stats"]:
-            print(
-                f"  [replica {s['replica']}] requests={s['requests']} "
-                f"p50={s['latency_p50_ms']:.1f}ms "
-                f"p99={s['latency_p99_ms']:.1f}ms "
-                f"applied_step={s['applied_step']} "
-                f"lag={s['lag_steps']} alive={s['alive']}"
-            )
-        return
-    if not args.arch:
-        ap.error("--arch is required unless --manifold is given")
-    out = generate(
-        args.arch,
-        batch=args.batch,
-        prompt_len=args.prompt_len,
-        gen_len=args.gen_len,
-        smoke=args.smoke,
-        temperature=args.temperature,
+    mesh_shape = None
+    if args.mesh:
+        parts = args.mesh.lower().split("x")
+        if len(parts) != 2 or not all(p.isdigit() and p for p in parts):
+            ap.error("--mesh must look like 4x2 (data x model)")
+        mesh_shape = (int(parts[0]), int(parts[1]))
+    out = serve_manifold(
+        n_base=args.n_base,
+        n_stream=args.n_stream,
+        stream_batch=args.stream_batch,
+        max_latency_ms=args.max_latency_ms,
+        arrival=args.arrival,
+        k=args.k,
+        d=args.d,
+        block=args.block,
+        seed=args.seed,
+        checkpoint_dir=args.checkpoint_dir,
+        resume=args.resume,
+        checkpoint_secs=args.checkpoint_secs,
+        absorb=args.absorb,
+        mesh_shape=mesh_shape,
+        regime=args.regime,
+        landmarks=args.landmarks,
+        objective=args.objective,
+        replicas=args.replicas,
+        router_vnodes=args.router,
+        pipeline_depth=args.pipeline_depth,
     )
     print(
-        f"[serve {args.arch}] prefill={out['prefill_s']:.2f}s "
-        f"decode={out['decode_s']:.2f}s ({out['tok_per_s']:.1f} tok/s)"
+        f"[serve manifold] regime={out['regime']} "
+        f"objective={out['objective']} "
+        f"fit={out['fit_s']:.2f}s "
+        f"serve={out['serve_s']:.3f}s "
+        f"({out['points_per_s']:.0f} pts/s) "
+        f"p50={out['latency_p50_ms']:.1f}ms "
+        f"p99={out['latency_p99_ms']:.1f}ms "
+        f"wait={out['queue_wait_ms']:.1f}ms "
+        f"service={out['service_ms']:.1f}ms "
+        f"mean_batch={out['mean_batch']:.1f} "
+        f"absorbed={out['absorbed']} v{out['serving_version']} "
+        f"err={out['procrustes_error']:.2e} "
+        f"rv={out['residual_variance']:.3f}"
     )
-    print("sample generation:", out["generated"][0][:16])
+    for s in out["replica_stats"]:
+        print(
+            f"  [replica {s['replica']}] requests={s['requests']} "
+            f"p50={s['latency_p50_ms']:.1f}ms "
+            f"p99={s['latency_p99_ms']:.1f}ms "
+            f"applied_step={s['applied_step']} "
+            f"lag={s['lag_steps']} alive={s['alive']}"
+        )
 
 
 if __name__ == "__main__":

@@ -1,11 +1,6 @@
 from repro.optim.adamw import (  # noqa: F401
     AdamWConfig,
-    adamw_init_specs,
     adamw_update,
     cosine_schedule,
     global_norm,
-)
-from repro.optim.compression import (  # noqa: F401
-    compress_decompress,
-    error_feedback_allreduce,
 )

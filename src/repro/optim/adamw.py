@@ -1,8 +1,8 @@
 """AdamW + schedule + gradient clipping, dependency-free.
 
-Optimizer state is declared as a ParamSpec tree mirroring the parameters'
-logical axes, so m/v shard exactly like their parameters (and can be
-further sharded for ZeRO-style partitioning by remapping the rules).
+The state is ``{"m": tree, "v": tree, "step": int32 scalar}``, with m and
+v zero trees shaped like the parameters (the stress objective of
+:mod:`repro.core.embedding` builds it that way).
 """
 from __future__ import annotations
 
@@ -11,8 +11,6 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-
-from repro.sharding import ParamSpec
 
 Tree = Any
 
@@ -47,20 +45,6 @@ def cosine_schedule(cfg: AdamWConfig, step: jax.Array) -> jax.Array:
     cos = 0.5 * (1 + jnp.cos(jnp.pi * prog))
     frac = cfg.min_lr_frac + (1 - cfg.min_lr_frac) * cos
     return cfg.lr * warm * frac
-
-
-def adamw_init_specs(param_specs: Tree) -> Tree:
-    """ParamSpec tree for (m, v) with the same logical sharding as params."""
-
-    def zeros_like_spec(s: ParamSpec) -> ParamSpec:
-        return ParamSpec(s.shape, s.logical, init="zeros", dtype=jnp.float32)
-
-    is_spec = lambda x: isinstance(x, ParamSpec)  # noqa: E731
-    return {
-        "m": jax.tree.map(zeros_like_spec, param_specs, is_leaf=is_spec),
-        "v": jax.tree.map(zeros_like_spec, param_specs, is_leaf=is_spec),
-        "step": ParamSpec((), (), init="zeros", dtype=jnp.int32),
-    }
 
 
 def global_norm(tree: Tree) -> jax.Array:

@@ -1,9 +1,4 @@
 from repro.sharding.logical import (  # noqa: F401
-    ParamSpec,
-    LogicalRules,
-    DEFAULT_RULES,
-    logical_to_sharding,
-    spec_shardings,
-    materialize,
-    eval_shape_tree,
+    folded_axis_index,
+    mesh_axis_size,
 )

@@ -1,6 +1,6 @@
 """Shared fixtures.  NOTE: tests run with the real single-CPU device count;
-only multi-device tests spawn subprocesses with XLA_FLAGS (so smoke tests
-and benches see 1 device, per the dry-run isolation requirement)."""
+only multi-device tests spawn subprocesses with XLA_FLAGS, since jax fixes
+a process's device count when it first initializes."""
 import os
 import sys
 

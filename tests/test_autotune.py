@@ -166,18 +166,6 @@ def test_env_override_reaches_kernel_validation(rng, monkeypatch):
         ops.minplus_update(g, g, g, mode="pallas")
 
 
-def test_constants_are_shared_with_launch_rooflines():
-    """The stage-level roofline models must read the tuner's machine
-    constants (single source of truth)."""
-    from repro.launch import analytics
-
-    target = autotune.CHIPS[analytics.TARGET_CHIP]
-    assert analytics.VPU_OPS == target.vpu_ops
-    assert analytics.HBM_BW == target.hbm_bw
-    assert analytics.PEAK_FLOPS == target.peak_flops
-    assert not hasattr(autotune, "HBM_BW")  # no second, untargeted copy
-
-
 # --------------------------------------------- Phase-2 split-panel auto ----
 
 

@@ -7,7 +7,7 @@ Covers the acceptance scenario (fit on 4x2 killed at the center boundary,
 resume on 2x4) plus the harder mid-APSP variant: a segment checkpoint
 written halfway through the panel loop on 4x2 re-enters the panel loop on
 2x4.  Runs in a subprocess with 8 fake CPU devices so the rest of the
-suite keeps the real 1-device view (dry-run isolation rule)."""
+suite keeps the real 1-device view."""
 import os
 import subprocess
 import sys

@@ -325,7 +325,7 @@ print("ALL-OBJECTIVE-MESH-OK")
 def test_objectives_on_mesh_backend():
     """All three objectives serve and absorb through MeshBackend, and
     mesh serving matches local within float tolerance (subprocess with 8
-    fake CPU devices, dry-run isolation rule)."""
+    fake CPU devices)."""
     import os
     import subprocess
     import sys

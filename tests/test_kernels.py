@@ -8,6 +8,7 @@ import jax.numpy as jnp
 from repro.kernels import ops, ref
 from repro.kernels.minplus import minplus as mp_pallas
 from repro.kernels.minplus_border import minplus_border as mb_pallas
+from repro.kernels.minplus_update import minplus_update as mpu_pallas
 from repro.kernels.minplus_panel import (
     minplus_panel_col as mpc_pallas,
     minplus_panel_row as mpr_pallas,
@@ -17,22 +18,22 @@ from repro.kernels.knn_topk import PAD_IDX
 from repro.kernels.pairwise_dist import pairwise_sq_dists as pd_pallas
 
 
-@pytest.mark.parametrize(
-    "m,k,n,bm,bn,bk,unroll",
-    [
-        (32, 32, 32, 32, 32, 32, 4),
-        (64, 128, 96, 32, 32, 64, 8),
-        (128, 64, 128, 64, 64, 32, 8),
-        (256, 256, 256, 128, 128, 128, 16),
-        (8, 8, 8, 8, 8, 8, 1),
-        # register sub-tiles: one 8-row group over a 1024-lane block; 24
-        # rows (three groups) over 384 lanes and a 96-deep contraction;
-        # 24 rows over one 128-lane strip, two contraction steps
-        (16, 128, 1024, 8, 1024, 128, 8),
-        (48, 96, 384, 24, 384, 96, 8),
-        (24, 256, 256, 24, 128, 128, 8),
-    ],
-)
+MINPLUS_TILINGS = [
+    (32, 32, 32, 32, 32, 32, 4),
+    (64, 128, 96, 32, 32, 64, 8),
+    (128, 64, 128, 64, 64, 32, 8),
+    (256, 256, 256, 128, 128, 128, 16),
+    (8, 8, 8, 8, 8, 8, 1),
+    # register sub-tiles: one 8-row group over a 1024-lane block; 24
+    # rows (three groups) over 384 lanes and a 96-deep contraction;
+    # 24 rows over one 128-lane strip, two contraction steps
+    (16, 128, 1024, 8, 1024, 128, 8),
+    (48, 96, 384, 24, 384, 96, 8),
+    (24, 256, 256, 24, 128, 128, 8),
+]
+
+
+@pytest.mark.parametrize("m,k,n,bm,bn,bk,unroll", MINPLUS_TILINGS)
 def test_minplus_matches_ref(m, k, n, bm, bn, bk, unroll, rng):
     a = rng.uniform(0, 10, (m, k)).astype(np.float32)
     b = rng.uniform(0, 10, (k, n)).astype(np.float32)
@@ -50,6 +51,29 @@ def test_minplus_with_inf(rng):
     want = np.min(a[:, :, None] + b[None, :, :], axis=1)
     got = mp_pallas(a, b, bm=32, bn=32, bk=32, unroll=4, interpret=True)
     np.testing.assert_allclose(got, want, rtol=1e-6)
+
+
+@pytest.mark.parametrize("inf", [False, True], ids=["finite", "inf"])
+@pytest.mark.parametrize("m,k,n,bm,bn,bk,unroll", MINPLUS_TILINGS)
+def test_minplus_update_matches_ref(m, k, n, bm, bn, bk, unroll, inf, rng):
+    """The APSP update O = min(E, C (x) R), its accumulator seeded from E
+    as the fit seeds it, bit-identical to the oracle at every tiling of
+    the plain product; the inf inputs carry +inf entries, as the graph
+    does before closure."""
+    e = rng.uniform(0, 20, (m, n)).astype(np.float32)
+    c = rng.uniform(0, 10, (m, k)).astype(np.float32)
+    r = rng.uniform(0, 10, (k, n)).astype(np.float32)
+    if inf:
+        for x in (e, c, r):
+            x[rng.uniform(size=x.shape) < 0.3] = np.inf
+    want = np.minimum(e, np.min(c[:, :, None] + r[None, :, :], axis=1))
+    got = np.asarray(mpu_pallas(
+        e, c, r, bm=bm, bn=bn, bk=bk, unroll=unroll, interpret=True
+    ))
+    oracle = np.asarray(ref.minplus_update_ref(e, c, r))
+    assert np.array_equal(got, oracle)
+    np.testing.assert_allclose(got, want, rtol=1e-6)
+    assert np.array_equal(np.isinf(got), np.isinf(want))
 
 
 def _closed_diag_block(rng, b):

@@ -4,7 +4,7 @@ with the LocalBackend results within 1e-5 on a >=4-device mesh, and the
 batched request queue must serve correctly on top of the mesh mapper.
 
 Runs in a subprocess with 8 fake CPU devices so the rest of the suite
-keeps the real 1-device view (dry-run isolation rule)."""
+keeps the real 1-device view."""
 import os
 import subprocess
 import sys

@@ -11,7 +11,6 @@ from hypothesis.extra import numpy as hnp  # noqa: E402
 
 from repro.core import centering, metrics
 from repro.kernels import ref
-from repro.optim import compress_decompress
 
 # integer-valued floats dodge fp non-associativity in semiring checks
 _vals = st.integers(min_value=0, max_value=50).map(float)
@@ -85,21 +84,6 @@ def test_procrustes_similarity_invariant(x, scale, tx):
     y = x * scale + tx
     err = float(metrics.procrustes_error(jnp.asarray(x), jnp.asarray(y)))
     assert err < 1e-5
-
-
-@settings(max_examples=20, deadline=None)
-@given(
-    g=hnp.arrays(
-        np.float32, (64,),
-        elements=st.floats(-100, 100, width=32),
-    )
-)
-def test_compression_error_bounded(g):
-    deq, resid = compress_decompress(jnp.asarray(g))
-    # quantization error bounded by half a step
-    step = np.max(np.abs(g)) / 127.0 + 1e-12
-    assert np.max(np.abs(np.asarray(resid))) <= step * 0.51 + 1e-6
-    np.testing.assert_allclose(np.asarray(deq) + np.asarray(resid), g, atol=1e-5)
 
 
 @settings(max_examples=15, deadline=None)

@@ -295,16 +295,19 @@ def test_from_checkpoint_still_raises_when_nothing_usable(tmp_path):
 # ------------------------------------------------------------- serve CLI --
 
 
-def test_serve_cli_smoke_flag_is_toggleable():
-    """--smoke was store_true with default=True: full configs unreachable.
-    BooleanOptionalAction restores --no-smoke."""
+def test_serve_cli_smoke_flag_is_toggleable(capsys):
+    """--smoke is a BooleanOptionalAction (default on, --no-smoke turns it
+    off), and --manifold is required."""
     from repro.launch.serve import build_parser
 
     ap = build_parser()
-    assert ap.parse_args(["--arch", "smollm-135m"]).smoke is True
-    assert ap.parse_args(["--arch", "smollm-135m", "--smoke"]).smoke is True
-    assert ap.parse_args(["--arch", "smollm-135m", "--no-smoke"]).smoke \
-        is False
+    base = ["--manifold", "swissroll"]
+    assert ap.parse_args(base).smoke is True
+    assert ap.parse_args(base + ["--smoke"]).smoke is True
+    assert ap.parse_args(base + ["--no-smoke"]).smoke is False
+    with pytest.raises(SystemExit):
+        ap.parse_args(["--smoke"])
+    assert "--manifold" in capsys.readouterr().err
 
 
 def test_serve_manifold_reports_queue_stats(tmp_path):

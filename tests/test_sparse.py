@@ -64,13 +64,16 @@ def _random_padded_csr(rng, n, deg, *, integer=False):
 # ------------------------------------------------- frontier kernel oracle --
 
 
-@pytest.mark.parametrize("s", [4, 128])
+# 40 and 59: the last batch of 808 landmarks (one chip) and of a mesh
+# chip's 443 (808 % 128, 443 % 128)
+@pytest.mark.parametrize("s", [4, 40, 59, 128])
 @pytest.mark.parametrize("bn", [32, 40, 96])
 def test_frontier_relax_pallas_matches_ref(rng, bn, s):
     """Pallas(interpret) vs the chunked CSR reference, nodes-major (n, s),
-    bit-identical - including inf (padded) lanes, a batch under and at
-    the lane width, and a bn that does not divide n (the padded-frontier
-    masking path)."""
+    bit-identical - including inf (padded) lanes, batches under and at
+    the lane width (the tail widths the sparse cells end each fit with),
+    and a bn that does not divide n (the padded-frontier masking
+    path)."""
     n, deg = 96, 5
     nbr, w, _ = _random_padded_csr(np.random.default_rng(3), n, deg)
     dist = jnp.full((n, s), jnp.inf, jnp.float32)
@@ -277,16 +280,26 @@ def test_csr_graph_ignores_knn_pad_lanes():
 # ----------------------------------------------------- landmark selection --
 
 
-def test_hierarchical_landmarks_deterministic():
-    x, _ = euler_isometric_swiss_roll(200, seed=4)
+@pytest.mark.parametrize(
+    "n,m", [(200, 48), (257, 37), (640, 101), (1000, 128)]
+)
+def test_hierarchical_landmarks_deterministic(n, m):
+    """Exactly m distinct sorted indices, the same picks for the same
+    seed's points; m off a square and n off a power of two leave the
+    cells uneven quotas."""
+    x, _ = euler_isometric_swiss_roll(n, seed=4)
     from repro.core import knn
 
-    d, _ = knn.knn_blocked(jnp.asarray(x), k=8, block=100)
-    a = hierarchical_landmarks(x, np.asarray(d), m=48)
-    b = hierarchical_landmarks(np.asarray(x).copy(), np.asarray(d), m=48)
+    d, _ = knn.knn_blocked(jnp.asarray(x), k=8, block=n)
+    a = hierarchical_landmarks(x, np.asarray(d), m=m)
+    b = hierarchical_landmarks(np.asarray(x).copy(), np.asarray(d), m=m)
     np.testing.assert_array_equal(a, b)
-    assert a.shape[0] == 48 and np.unique(a).shape[0] == 48
-    assert a.min() >= 0 and a.max() < 200
+    x2, _ = euler_isometric_swiss_roll(n, seed=4)
+    np.testing.assert_array_equal(
+        hierarchical_landmarks(x2, np.asarray(d), m=m), a
+    )
+    assert a.shape[0] == m and np.unique(a).shape[0] == m
+    assert a.min() >= 0 and a.max() < n
     assert np.all(np.sort(a) == a)
     # m == n degenerates to the identity
     np.testing.assert_array_equal(
